@@ -3,7 +3,8 @@
 Compiles the same smoke train step on an 8-chip submesh under both
 substrates and reports collective op counts/bytes from the HLO — the
 system-level analogue of the paper's Fig. 3 eLib speedup panel.  Runs in
-a subprocess so the main process keeps one device.
+a subprocess pinned to the CPU, so the main process keeps one device and
+the child never contends for a chip the parent holds.
 
   PYTHONPATH=src python -m benchmarks.bench_substrate
 """
@@ -24,7 +25,7 @@ SCRIPT = textwrap.dedent("""
     from repro.configs import smoke_config
     from repro.launch import build
     from repro.launch.mesh import make_mesh
-    from repro.launch.dryrun import _collective_bytes
+    from repro.launch.hlo import collective_bytes
 
     out = {}
     for comm in ("shmem", "xla"):
@@ -38,7 +39,7 @@ SCRIPT = textwrap.dedent("""
             compiled = jax.jit(wrap(batch), donate_argnums=(0, 1)).lower(
                 build.global_shape(ps, psp, mesh),
                 build.global_shape(os_, osp, mesh), batch).compile()
-        coll = _collective_bytes(compiled.as_text())
+        coll = collective_bytes(compiled.as_text())
         cost = compiled.cost_analysis()
         out[comm] = {"counts": coll["counts"], "bytes": coll["bytes"],
                      "flops": cost.get("flops", 0.0)}
@@ -49,6 +50,7 @@ SCRIPT = textwrap.dedent("""
 def run() -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)

@@ -77,16 +77,12 @@ def calibrated_link(machine: Machine) -> tuple[abmodel.LinkModel, str]:
     db_path = pathlib.Path(os.environ.get("BENCH_OUT_DIR",
                                           "bench-reports"))
     db_path = db_path / "tuning_db.json"
-    try:
-        if db_path.exists():
-            from repro.core import tuner as tun
-            db = tun.TuningDB.load(db_path)
-            lm = db.link_model(tun.fingerprint(machine.topo,
-                                               machine.n_pes))
-            if lm is not None:
-                return lm, "calibrated"
-    except Exception:
-        pass
+    if db_path.exists():
+        from repro.core import tuner as tun
+        db = tun.TuningDB.load(db_path)
+        lm = db.link_model(tun.fingerprint(machine.topo, machine.n_pes))
+        if lm is not None:
+            return lm, "calibrated"
     return machine.link, "default"
 
 
@@ -107,10 +103,7 @@ def noc_term(nbytes: float, machine: Machine,
 
 
 def _cost_analysis(compiled) -> dict:
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):       # older jax returns [dict]
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    return dict(compiled.cost_analysis() or {})
 
 
 def _timed_us(fn, *args, iters: int = 3) -> float:

@@ -25,6 +25,7 @@ import re
 import socket
 import sys
 import time
+import traceback
 
 sys.path.insert(0, "src")
 
@@ -59,22 +60,18 @@ def machine_fingerprint() -> dict:
     """Hostname/CPU/jax-stack identity stamped into every BENCH_*.json
     header — wall times are only comparable within one fingerprint
     (check_regression warns loudly when they differ)."""
-    fp = {
+    import jax
+    import jaxlib
+    return {
         "hostname": socket.gethostname(),
         "cpus": os.cpu_count(),
         "platform": platform.platform(),
         "python": platform.python_version(),
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "xla_backend": jax.default_backend(),
+        "device_count": jax.device_count(),
     }
-    try:
-        import jax
-        import jaxlib
-        fp["jax"] = jax.__version__
-        fp["jaxlib"] = jaxlib.__version__
-        fp["xla_backend"] = jax.default_backend()
-        fp["device_count"] = jax.device_count()
-    except Exception:
-        fp["jax"] = None
-    return fp
 
 
 def _run_paper():
@@ -95,8 +92,9 @@ def _module_runner(modname: str, header: str):
     return run
 
 
-# Ordered registry: (key, fatal?, runner).  Non-fatal benches report and
-# continue (subprocess-heavy or optional ones).
+# Ordered registry: (key, fatal?, runner).  A fatal bench's failure stops
+# the harness; a non-fatal one is reported, the rest still run, and the
+# harness exits non-zero at the end.
 BENCHES = [
     ("paper", True, _run_paper),
     ("patterns", False, _module_runner(
@@ -145,16 +143,22 @@ def main(argv=None) -> None:
     if unknown:
         raise SystemExit(f"unknown bench keys: {sorted(unknown)}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     rows: list[dict] = []
+    failed: list[str] = []
     for key, fatal, runner in BENCHES:
         if only and key not in only:
             continue
         try:
             mod = runner()
-        except Exception as e:
+        except Exception:
             if fatal:
                 raise
-            print(f"{key} bench skipped: {e}")
+            traceback.print_exc()
+            print(f"{key} bench FAILED")
+            failed.append(key)
             continue
         for name, us, derived in getattr(mod, "ROWS", []):
             rows.append(_std_row(key, name, us, str(derived)))
@@ -169,6 +173,8 @@ def main(argv=None) -> None:
                "rows": rows}
         out.write_text(json.dumps(doc, indent=1))
         print(f"\n[run] wrote {len(rows)} rows to {out}")
+    if failed:
+        raise SystemExit(f"[run] benches failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

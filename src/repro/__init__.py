@@ -1,6 +1,3 @@
 """repro: ShmemJAX — ARL OpenSHMEM for Epiphany, rebuilt for TPU pods in JAX."""
-from . import _compat
-
-_compat.install()
 
 __version__ = "1.0.0"

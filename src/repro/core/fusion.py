@@ -68,9 +68,8 @@ def ring_attention(ctx, q, k, v, q_pos, k_pos, *, causal: bool = True,
     net = ctx.net
     n = net.n_pes
     kw = dict(causal=causal, window=window, softcap=softcap,
-              sm_scale=sm_scale, use_pallas=use_pallas, bq=bq, bk=bk)
-    if interpret is not None:
-        kw["interpret"] = interpret
+              sm_scale=sm_scale, use_pallas=use_pallas, bq=bq, bk=bk,
+              interpret=interpret)
 
     def partials(q_, k_, v_, qp_, kp_):
         return _ra.attn_block_partials(q_, k_, v_, qp_, kp_, **kw)

@@ -41,11 +41,8 @@ from typing import Any, Callable
 def trace_clean() -> bool:
     """True when called OUTSIDE any JAX trace — wall times measured here
     are execution times; under tracing they are staging times."""
-    try:
-        import jax
-        return bool(jax.core.trace_state_clean())
-    except Exception:       # very old/new jax: assume eager
-        return True
+    import jax
+    return jax.core.trace_ctx.is_top_level()
 
 
 @dataclasses.dataclass

@@ -30,10 +30,6 @@ NEG_INF = -1e30
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, lk_pad: int, lk_valid: int,
                  bk: int, causal: bool, window: int | None,
                  softcap: float | None, sm_scale: float, q_start_map):
-    # NOTE: refs are indexed with slices only (never bare python ints):
-    # the pinned jax's interpret-mode discharge rule rejects scalar int
-    # indices inside pl.load/pl.store (AttributeError on `.shape`), and
-    # slice indexing lowers identically on the compiled path.
     qb = pl.program_id(2)
     q = q_ref[...][0, 0].astype(jnp.float32) * sm_scale  # (BQ, D)
     bq, d = q.shape
@@ -44,9 +40,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, lk_pad: int, lk_valid: int,
     def body(i, carry):
         acc, m_i, l_i = carry
         start = i * bk
-        kv_idx = (slice(None), slice(None), pl.ds(start, bk), slice(None))
-        k = pl.load(k_ref, kv_idx)[0, 0].astype(jnp.float32)     # (BK, D)
-        v = pl.load(v_ref, kv_idx)[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, pl.ds(start, bk), :].astype(jnp.float32)  # (BK, D)
+        v = v_ref[0, 0, pl.ds(start, bk), :].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (BQ, BK)

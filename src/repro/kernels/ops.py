@@ -4,8 +4,8 @@ Each wrapper:
   * handles the unaligned edge case by padding to tile multiples (the TPU
     analogue of the paper's unaligned-memory specialization in the put
     copy loop) and un-padding the result;
-  * dispatches kernel vs. pure-jnp reference via `use_pallas` — on this
-    CPU container kernels run with interpret=True for validation, while
+  * dispatches kernel vs. pure-jnp reference via `use_pallas` — off a
+    TPU, kernels default to interpret mode for validation, while
     the models/dry-run default to the XLA reference path (DESIGN.md);
   * makes attention differentiable with a custom VJP whose backward
     recomputes through the reference (flash-style remat).

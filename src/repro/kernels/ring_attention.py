@@ -20,8 +20,7 @@ Masking is GLOBAL-position based: the caller passes the query rows'
 positions and each KV block's positions (`k_pos`, with -1 marking padded
 slots) so causal / sliding-window / ragged-edge semantics survive the
 sequence sharding — a block's rows mask exactly as they would have in the
-monolithic kernel.  Same pinned-jax constraint as flash_attention: refs
-are indexed with slices only.
+monolithic kernel.
 """
 from __future__ import annotations
 
@@ -33,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .flash_attention import DEFAULT_BK, DEFAULT_BQ, NEG_INF
+from .ops import _default_interpret
 
 
 def _partials_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref,
@@ -41,22 +41,21 @@ def _partials_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref,
                      softcap: float | None, sm_scale: float):
     q = q_ref[...][0, 0].astype(jnp.float32) * sm_scale     # (BQ, D)
     bq, d = q.shape
-    q_pos = qp_ref[...].reshape(bq, 1)
+    q_pos = qp_ref[...]                                     # (BQ, 1)
 
     n_kb = lk_pad // bk
 
     def body(i, carry):
         acc, m_i, l_i = carry
         start = i * bk
-        kv_idx = (slice(None), slice(None), pl.ds(start, bk), slice(None))
-        k = pl.load(k_ref, kv_idx)[0, 0].astype(jnp.float32)     # (BK, D)
-        v = pl.load(v_ref, kv_idx)[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, pl.ds(start, bk), :].astype(jnp.float32)  # (BK, D)
+        v = v_ref[0, 0, pl.ds(start, bk), :].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (BQ, BK)
         if softcap is not None:
             logits = softcap * jnp.tanh(logits / softcap)
-        k_pos = pl.load(kp_ref, (pl.ds(start, bk),)).reshape(1, bk)
+        k_pos = kp_ref[pl.ds(i, 1), :]                           # (1, BK)
         mask = k_pos >= 0                    # -1 marks padded KV slots
         if causal:
             mask &= k_pos <= q_pos
@@ -77,8 +76,8 @@ def _partials_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref,
     l0 = jnp.zeros((bq, 1), jnp.float32)
     acc, m_i, l_i = jax.lax.fori_loop(0, n_kb, body, (acc0, m0, l0))
     acc_ref[...] = acc[None, None]
-    m_ref[...] = m_i[:, 0][None, None]
-    l_ref[...] = l_i[:, 0][None, None]
+    m_ref[...] = m_i[None, None]
+    l_ref[...] = l_i[None, None]
 
 
 def _partials_pallas(q, k, v, q_pos, k_pos, *, causal, window, softcap,
@@ -90,28 +89,29 @@ def _partials_pallas(q, k, v, q_pos, k_pos, *, causal, window, softcap,
         _partials_kernel, lk_pad=lk, bk=bk, causal=causal, window=window,
         softcap=softcap, sm_scale=sm_scale)
     grid = (b_sz, hq, lq // bq)
-    return pl.pallas_call(
+    acc, m, l = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, lk, d), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, lk, d), lambda b, h, i: (b, h // group, 0, 0)),
-            pl.BlockSpec((bq,), lambda b, h, i: (i,)),
-            pl.BlockSpec((lk,), lambda b, h, i: (0,)),
+            pl.BlockSpec((bq, 1), lambda b, h, i: (i, 0)),
+            pl.BlockSpec((lk // bk, bk), lambda b, h, i: (0, 0)),
         ],
         out_specs=(
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b_sz, hq, lq, d), jnp.float32),
-            jax.ShapeDtypeStruct((b_sz, hq, lq), jnp.float32),
-            jax.ShapeDtypeStruct((b_sz, hq, lq), jnp.float32),
+            jax.ShapeDtypeStruct((b_sz, hq, lq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b_sz, hq, lq, 1), jnp.float32),
         ),
         interpret=interpret,
-    )(q, k, v, q_pos, k_pos)
+    )(q, k, v, q_pos.reshape(lq, 1), k_pos.reshape(lk // bk, bk))
+    return acc, m[..., 0], l[..., 0]
 
 
 def _partials_ref(q, k, v, q_pos, k_pos, *, causal, window, softcap,
@@ -147,19 +147,22 @@ def attn_block_partials(q, k, v, q_pos, k_pos, *, causal: bool = True,
                         sm_scale: float | None = None,
                         use_pallas: bool = False,
                         bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """Un-normalized flash partials of q against ONE KV block.
 
     q: (B, Hq, Lq, D); k, v: (B, Hkv, Lk, D); q_pos: (Lq,) int32 global
     query positions; k_pos: (Lk,) int32 global key positions (-1 = padded
     slot, always masked).  Returns (acc f32 (B,Hq,Lq,D), m f32 (B,Hq,Lq),
-    l f32 (B,Hq,Lq)) — merge with `merge_partials`, then `finalize`."""
+    l f32 (B,Hq,Lq)) — merge with `merge_partials`, then `finalize`.
+    The Pallas path runs in interpret mode unless the backend is a TPU."""
     d = q.shape[-1]
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     if not use_pallas:
         return _partials_ref(q, k, v, q_pos, k_pos, causal=causal,
                              window=window, softcap=softcap,
                              sm_scale=sm_scale)
+    if interpret is None:
+        interpret = _default_interpret()
     lq, lk = q.shape[2], k.shape[2]
     pq = (-lq) % bq
     pk = (-lk) % bk

@@ -17,7 +17,6 @@ Results land in experiments/dryrun/<arch>__<shape>__<mesh>__<comm>.json
 import argparse
 import json
 import pathlib
-import re
 import sys
 import time
 
@@ -25,40 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def _collective_bytes(hlo_text: str) -> dict:
-    """Sum operand bytes of collective ops in the (scheduled) HLO."""
-    dtypes = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
-              "u8": 1, "f64": 8, "s64": 8, "pred": 1, "s16": 2, "u16": 2}
-    kinds = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-             "collective-permute")
-    out = {k: 0 for k in kinds}
-    counts = {k: 0 for k in kinds}
-    shape_re = re.compile(r"(\w+)\[([\d,]*)\]")
-    for line in hlo_text.splitlines():
-        ls = line.strip()
-        m = re.match(r"%?[\w\.\-]+ = (.*?)\s*(all-gather|all-reduce|"
-                     r"reduce-scatter|all-to-all|collective-permute)", ls)
-        if not m:
-            continue
-        kind = m.group(2)
-        if "-start" in ls.split("=")[1].split("(")[0]:
-            pass  # async starts counted; done ops carry no new bytes
-        if re.search(rf"{kind}-done", ls):
-            continue
-        shapes = shape_re.findall(m.group(1))
-        nbytes = 0
-        for dt, dims in shapes:
-            if dt not in dtypes:
-                continue
-            n = 1
-            for d in dims.split(","):
-                if d:
-                    n *= int(d)
-            nbytes += n * dtypes[dt]
-        out[kind] += nbytes
-        counts[kind] += 1
-    return {"bytes": out, "counts": counts}
+from .hlo import collective_bytes
 
 
 # v5e-class hardware constants (per chip)
@@ -132,7 +98,7 @@ def run_cell(arch: str, shape: str, multipod: bool, comm: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    coll = _collective_bytes(compiled.as_text())
+    coll = collective_bytes(compiled.as_text())
     terms = roofline_terms(cost, coll, n_chips)
     res = {
         "cell": cell, "status": "ok",

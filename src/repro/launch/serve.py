@@ -121,6 +121,8 @@ def main(argv=None):
                          "dump the registry JSON here at exit")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..configs import get_config, smoke_config
     from ..models import transformer
     from .mesh import make_mesh

@@ -96,6 +96,8 @@ def main(argv=None):
                          "axis as extra DP (§Perf P6)")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..configs import get_config, smoke_config
     from ..ckpt import manager as ckpt
     from ..data.pipeline import SyntheticLM

@@ -135,7 +135,7 @@ def test_put_get_patterns():
 
 def test_collective_bytes_parser():
     """The dry-run HLO collective parser sums operand bytes correctly."""
-    from repro.launch.dryrun import _collective_bytes
+    from repro.launch.hlo import collective_bytes
     hlo = """
   %ag = bf16[8,128]{1,0} all-gather(bf16[1,128]{1,0} %x), dimensions={0}
   %ar = f32[256]{0} all-reduce(f32[256]{0} %y), to_apply=%add
@@ -143,7 +143,7 @@ def test_collective_bytes_parser():
   %a2a = s8[64]{0} all-to-all(s8[64]{0} %w), dimensions={0}
   %done = f32[4]{0} all-reduce-done(f32[4]{0} %h)
 """
-    out = _collective_bytes(hlo)
+    out = collective_bytes(hlo)
     # payload proxy: the op's OUTPUT shape bytes (done-ops excluded)
     assert out["bytes"]["all-gather"] == 8 * 128 * 2
     assert out["bytes"]["all-reduce"] == 256 * 4

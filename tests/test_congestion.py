@@ -570,7 +570,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import _compat
 from repro.core import collectives as coll, spmd_ctx
 from repro.core.topology import MeshTopology
 from repro.parallel.comm import AxisSpec, Comm

@@ -198,12 +198,14 @@ def test_engine_eos_stops_early(prompts):
     eng = _make_engine()
     r = eng.submit(prompts[0], 8)
     eng.run()
-    eos = int(eng.results[r][2])              # force eos at the 3rd token
+    toks = eng.results[r]
+    eos = int(toks[2])                        # force eos by the 3rd token
+    stop = int(np.argmax(toks == eos)) + 1    # its first occurrence ends it
     eng2 = _make_engine(params=eng.params, eos_id=eos)
     r2 = eng2.submit(prompts[0], 8)
     eng2.run()
-    assert len(eng2.results[r2]) == 3
-    assert np.array_equal(eng2.results[r2], eng.results[r][:3])
+    assert len(eng2.results[r2]) == stop <= 3
+    assert np.array_equal(eng2.results[r2], toks[:stop])
 
 
 # ---------------------------------------------------------------------------

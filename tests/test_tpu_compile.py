@@ -1,0 +1,101 @@
+"""Compile the Pallas kernels for a described TPU v5e (no chip attached).
+
+The TPU compiler refuses what interpret mode accepts: blocks that break
+the (8, 128) tiling rule, unaligned dynamic slices, more VMEM than a
+kernel may use.  Each test lowers one kernel at real widths for one chip
+of a described ``v5e:2x2`` host and checks that the compiled program
+holds the Mosaic kernel.  Nothing runs, so nothing here is a timing.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import flash_attention as fa
+from repro.kernels import fused_update as fu
+from repro.kernels import put_copy as pc
+from repro.kernels import reduce_combine as rc
+from repro.kernels import ring_attention as ra
+from repro.kernels import ssd_scan as ss
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    # a described-topology compile cannot be read back without a chip
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("L", [1024, 4096])
+def test_flash_attention_qwen2_widths(spec, L):
+    # qwen2-0.5b: 14 query heads, 2 KV heads of 64
+    q = spec((1, 14, L, 64), jnp.bfloat16)
+    kv = spec((1, 2, L, 64), jnp.bfloat16)
+    _compile(lambda q, k, v: fa.flash_attention(q, k, v), q, kv, kv)
+
+
+def test_ring_attention_partials(spec):
+    L = 1024
+    q = spec((1, 14, L, 64), jnp.bfloat16)
+    kv = spec((1, 2, L, 64), jnp.bfloat16)
+    pos = spec((L,), jnp.int32)
+    _compile(lambda q, k, v, qp, kp: ra.attn_block_partials(
+        q, k, v, qp, kp, use_pallas=True, interpret=False),
+        q, kv, kv, pos, pos)
+
+
+def test_ssd_scan_mamba2_widths(spec):
+    # mamba2-2.7b: d_inner 5120 = 80 heads of 64, state 128, chunk 128
+    B, L, H, P, N = 1, 2048, 80, 64, 128
+    _compile(lambda *a: ss.ssd_scan(*a, chunk=128),
+             spec((B, L, H, P), jnp.bfloat16), spec((B, L, H), jnp.float32),
+             spec((H,), jnp.float32), spec((B, L, 1, N), jnp.bfloat16),
+             spec((B, L, 1, N), jnp.bfloat16),
+             spec((B, H, P, N), jnp.float32))
+
+
+def test_reduce_combine_4mib(spec):
+    bufs = [spec((1024, 1024), jnp.float32)] * 3
+    _compile(lambda *b: rc.reduce_combine_2d(list(b), "sum"), *bufs)
+
+
+def test_put_copy_4mib(spec):
+    _compile(pc.put_copy_2d, spec((1024, 1024), jnp.float32))
+
+
+def test_fused_adam_update_4mib(spec):
+    n = 1 << 20
+    f32 = spec((n,), jnp.float32)
+    scalar = spec((), jnp.float32)
+    _compile(lambda g0, g1, p, m, v, wd, c1, c2: fu.fused_adam_update_2d(
+        [g0, g1], p, m, v, wd, c1, c2, lr=3e-4, b1=0.9, b2=0.95, eps=1e-8,
+        wd_coef=0.1, scale=4.0, out_dtype=jnp.float32),
+        f32, f32, f32, f32, f32, spec((n,), jnp.int8), scalar, scalar)

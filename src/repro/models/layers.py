@@ -91,6 +91,7 @@ def embed(comm: Comm, cfg: ModelConfig, p: Params, tokens):
     return emb.astype(cfg.dtype)
 
 
+@jax.named_scope("lm_head")
 def lm_logits(comm: Comm, cfg: ModelConfig, p: Params, x):
     w = p["table"].T if cfg.tie_embeddings else p["head"]
     return _dense(x, w.astype(cfg.logit_dtype))   # (B, L, V_local)
@@ -397,6 +398,7 @@ def _cache_attend(cfg, q, ck, cv, valid, slot_map=None, comm=None,
 # Paged KV attention (serving engine, DESIGN.md §15)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kv_update")
 def paged_kv_update(pool_leaf, page_table, new, positions, page_size: int):
     """Scatter per-position rows into a paged KV pool.
 
@@ -414,6 +416,7 @@ def paged_kv_update(pool_leaf, page_table, new, positions, page_size: int):
     return pool_leaf.at[phys, off].set(new.astype(pool_leaf.dtype))
 
 
+@jax.named_scope("kv_gather")
 def paged_kv_gather(pool_leaf, page_table):
     """Gather a sequence-contiguous (B, S_max, ...) view of each row's
     pages (S_max = max_pages * page_size).  Invalid/unallocated table
@@ -424,6 +427,7 @@ def paged_kv_gather(pool_leaf, page_table):
     return got.reshape((B, P * ps) + got.shape[3:])
 
 
+@jax.named_scope("attend")
 def _attend_mq(cfg, q, ck, cv, valid, slot_map=None):
     """Multi-query generalization of `_cache_attend` for the paged path.
 
@@ -478,11 +482,12 @@ def attention_paged(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
     B, L, d = x.shape
     hd = cfg.hd
     nq_local, nkv_store, kv_repl = _gqa_dims(cfg, tp)
-    q = _dense(x, p["wq"], p.get("bq")).reshape(B, L, nq_local, hd)
-    k = _dense(x, p["wk"], p.get("bk")).reshape(B, L, nkv_store, hd)
-    v = _dense(x, p["wv"], p.get("bv")).reshape(B, L, nkv_store, hd)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn_proj"):
+        q = _dense(x, p["wq"], p.get("bq")).reshape(B, L, nq_local, hd)
+        k = _dense(x, p["wk"], p.get("bk")).reshape(B, L, nkv_store, hd)
+        v = _dense(x, p["wv"], p.get("bv")).reshape(B, L, nkv_store, hd)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
 
     slot_map = None
     if kv_repl:
@@ -513,7 +518,8 @@ def attention_paged(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
         _, valid_h = _head_ids(comm, cfg, tp)
         out = out * valid_h[None, None, :, None]
     out = out.reshape(B, L, nq_local * hd).astype(cfg.dtype)
-    y = _dense(out, p["wo"])
+    with jax.named_scope("attn_proj"):
+        y = _dense(out, p["wo"])
     return comm.allreduce(y, comm.axes.model), {"k": pk, "v": pv}
 
 
@@ -645,6 +651,7 @@ def init_mlp(key, cfg: ModelConfig, tp: int, d_ff: int | None = None) -> Params:
     }
 
 
+@jax.named_scope("mlp")
 def mlp(comm: Comm, cfg: ModelConfig, p: Params, x):
     h = jax.nn.silu(_dense(x, p["w_gate"])) * _dense(x, p["w_up"])
     return comm.allreduce(_dense(h, p["w_down"]), comm.axes.model)

@@ -46,6 +46,7 @@ class Request:
     rid: int
     prompt: np.ndarray
     max_new: int
+    t_submit: float              # scheduler clock at submit
 
 
 @dataclasses.dataclass
@@ -54,6 +55,8 @@ class SlotState:
     prompt: np.ndarray
     max_new: int
     pos: int                     # next position to be written by decode
+    t_submit: float              # scheduler clock at submit
+    t_admit: float               # scheduler clock at admission
     out: list = dataclasses.field(default_factory=list)
     done: bool = False
 
@@ -67,11 +70,16 @@ class Scheduler:
     request cannot starve behind a stream of small ones.  Eviction scans
     slots in index order each step.  Given the same submission sequence
     and per-slot completion times, the (admit, evict) event order is a
-    pure function of the trace."""
+    pure function of the trace.
 
-    def __init__(self, kv: PagedKV, page_size: int):
+    `clock` stamps each request at submit and at admission (two reads
+    per request), so `t_admit - t_submit` is its queue wait."""
+
+    def __init__(self, kv: PagedKV, page_size: int,
+                 clock=time.perf_counter):
         self.kv = kv
         self.page_size = int(page_size)
+        self.clock = clock
         self.queue: collections.deque[Request] = collections.deque()
         self.slots: list[SlotState | None] = [None] * kv.max_slots
         self._next_rid = 0
@@ -85,7 +93,7 @@ class Scheduler:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) == 0:
             raise ValueError("empty prompt")
-        req = Request(self._next_rid, prompt, int(max_new))
+        req = Request(self._next_rid, prompt, int(max_new), self.clock())
         if self.pages_needed(req) > self.kv.max_pages:
             raise ValueError(
                 f"request needs {self.pages_needed(req)} pages "
@@ -120,7 +128,8 @@ class Scheduler:
             self.kv.admit(slot, req.rid, need,
                           len(req.prompt) + req.max_new)
             state = SlotState(rid=req.rid, prompt=req.prompt,
-                              max_new=req.max_new, pos=len(req.prompt))
+                              max_new=req.max_new, pos=len(req.prompt),
+                              t_submit=req.t_submit, t_admit=self.clock())
             self.slots[slot] = state
             self.n_admitted += 1
             out.append((slot, state))
@@ -263,14 +272,41 @@ class ServeEngine:
                 (P(), lg_spec, poolspecs)))
 
     # -- observability helpers ------------------------------------------------
+    @contextlib.contextmanager
     def _span(self, name: str, **meta):
-        """Nested tracer span, bare profiler op, or nothing — the whole
-        disabled cost is this attribute test."""
-        if self._trace is not None and self._trace.enabled:
-            return self._trace.span(name, **meta)
-        if self.profile is not None and self.profile.enabled:
-            return self.profile.op(name, kind="span")
-        return contextlib.nullcontext()
+        """The engine's one instrumentation point.  Always a
+        `jax.profiler.TraceAnnotation`, which puts the phase on the
+        profiler's clock beside the device's operations and costs about
+        a microsecond when no profiler session is open; also a nested
+        tracer span or a bare profiler op when one is attached."""
+        with self._jax.profiler.TraceAnnotation(name):
+            if self._trace is not None and self._trace.enabled:
+                with self._trace.span(name, **meta):
+                    yield
+            elif self.profile is not None and self.profile.enabled:
+                with self.profile.op(name, kind="span"):
+                    yield
+            else:
+                yield
+
+    def program_texts(self) -> dict[str, str]:
+        """Compiled HLO text of the prefill and decode programs at this
+        engine's shapes, whose metadata carries the model's named scopes
+        (kv_update, kv_gather, attend, attn_proj, mlp, lm_head, sample)."""
+        jax, jnp = self._jax, self._jnp
+        table = self.kv.table
+        Lb = self.prompt_bucket
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+        with jax.set_mesh(self.mesh):
+            pre = self._pjit.lower(self.params, self.pool,
+                                   i32(1, table.shape[1]), i32(1, Lb),
+                                   i32(1, Lb), i32(1))
+            dec = self._djit.lower(self.params, self.pool, i32(*table.shape),
+                                   i32(self.max_slots, 1), i32(self.max_slots))
+            return {"prefill_fn": pre.compile().as_text(),
+                    "decode_fn": dec.compile().as_text()}
 
     def _req_event(self, kind: str, rid: int, **args) -> None:
         """Request-lifecycle edge on the tracer's async request track."""
@@ -291,7 +327,7 @@ class ServeEngine:
                 f"prompt longer than prompt_bucket={self.prompt_bucket}")
         rid = self.scheduler.submit(prompt, max_new)
         if self.metrics is not None:
-            self.metrics.on_submit(rid)
+            self.metrics.on_submit()
         self._req_event("enqueue", rid, prompt_len=len(
             np.asarray(prompt).reshape(-1)), max_new=int(max_new))
         return rid
@@ -337,7 +373,8 @@ class ServeEngine:
             self.kv.evict(i)
             sched.slots[i] = None
             self.logits_trace.pop(st.rid, None)
-            sched.queue.appendleft(Request(st.rid, st.prompt, st.max_new))
+            sched.queue.appendleft(Request(st.rid, st.prompt, st.max_new,
+                                           st.t_submit))
             requeued.append(st.rid)
         requeued.reverse()
         wall = time.perf_counter() - t0
@@ -358,35 +395,36 @@ class ServeEngine:
         jnp = self._jnp
         sched = self.scheduler
         metrics = self.metrics
-        with self._jax.set_mesh(self.mesh), \
-                self._span("serve.step", n_pes=0):
+        with self._jax.set_mesh(self.mesh), self._span("serve.step"):
             evicted = []
-            for slot, st in sched.step_evict():
-                self.results[st.rid] = np.asarray(st.out, np.int32)
-                evicted.append(st.rid)
-                if metrics is not None:
-                    metrics.on_evict(st.rid)
-                self._req_event("evict", st.rid, n_tokens=len(st.out))
+            with self._span("serve.evict"):
+                for slot, st in sched.step_evict():
+                    self.results[st.rid] = np.asarray(st.out, np.int32)
+                    evicted.append(st.rid)
+                    if metrics is not None:
+                        metrics.on_evict(st)
+                    self._req_event("evict", st.rid, n_tokens=len(st.out))
 
+            with self._span("serve.admit"):
+                admits = sched.step_admit()
+                if metrics is not None and sched.queue \
+                        and any(s is None for s in sched.slots):
+                    # free slot + waiting head = page backpressure, the
+                    # only reason FIFO admission stalls (DESIGN.md §15)
+                    metrics.on_backpressure()
             admitted = []
-            admits = sched.step_admit()
-            if metrics is not None and sched.queue \
-                    and any(s is None for s in sched.slots):
-                # free slot + waiting head = page backpressure, the only
-                # reason FIFO admission stalls (DESIGN.md §15)
-                metrics.on_backpressure()
             for slot, st in admits:
                 if metrics is not None:
-                    metrics.on_admit(st.rid)
+                    metrics.on_admit(st)
                 self._req_event("admit", st.rid, slot=slot)
-                Lb = self.prompt_bucket
-                toks = np.zeros((1, Lb), np.int32)
-                toks[0, :len(st.prompt)] = st.prompt
-                positions = jnp.broadcast_to(
-                    jnp.arange(Lb, dtype=jnp.int32)[None], (1, Lb))
-                trow = jnp.asarray(self.kv.table[slot:slot + 1])
-                last = jnp.asarray([len(st.prompt) - 1], jnp.int32)
-                with self._span("serve.prefill", nbytes=float(Lb * 4)):
+                with self._span("serve.prefill"):
+                    Lb = self.prompt_bucket
+                    toks = np.zeros((1, Lb), np.int32)
+                    toks[0, :len(st.prompt)] = st.prompt
+                    positions = jnp.broadcast_to(
+                        jnp.arange(Lb, dtype=jnp.int32)[None], (1, Lb))
+                    trow = jnp.asarray(self.kv.table[slot:slot + 1])
+                    last = jnp.asarray([len(st.prompt) - 1], jnp.int32)
                     tok, lg, self.pool = self._pjit(
                         self.params, self.pool, trow, jnp.asarray(toks),
                         positions, last)
@@ -395,33 +433,36 @@ class ServeEngine:
                            np.asarray(lg)[0] if self.capture_logits
                            else None)
                 if metrics is not None:
-                    metrics.on_first_token(st.rid)
+                    metrics.on_first_token(st)
                 self._req_event("first_token", st.rid)
                 admitted.append(st.rid)
 
             active = sched.active_slots()
             if active:
-                toks = np.zeros((self.max_slots, 1), np.int32)
-                poss = np.zeros((self.max_slots,), np.int32)
-                for i in active:
-                    st = sched.slots[i]
-                    toks[i, 0] = st.out[-1]
-                    poss[i] = st.pos
                 t0 = time.perf_counter()
+                with self._span("serve.decode.prepare"):
+                    toks = np.zeros((self.max_slots, 1), np.int32)
+                    poss = np.zeros((self.max_slots,), np.int32)
+                    for i in active:
+                        st = sched.slots[i]
+                        toks[i, 0] = st.out[-1]
+                        poss[i] = st.pos
+                    args = (jnp.asarray(self.kv.table), jnp.asarray(toks),
+                            jnp.asarray(poss))
                 with self._span("serve.decode", n_pes=len(active)):
                     tok, lg, self.pool = self._djit(
-                        self.params, self.pool, jnp.asarray(self.kv.table),
-                        jnp.asarray(toks), jnp.asarray(poss))
+                        self.params, self.pool, *args)
                     tok = np.asarray(tok)      # force sync: step complete
                 if metrics is not None:
                     metrics.on_decode_step(len(active),
                                            time.perf_counter() - t0)
-                lg = np.asarray(lg) if self.capture_logits else None
-                for i in active:
-                    st = sched.slots[i]
-                    st.pos += 1
-                    self._emit(st, tok[i],
-                               lg[i] if self.capture_logits else None)
+                with self._span("serve.emit"):
+                    lg = np.asarray(lg) if self.capture_logits else None
+                    for i in active:
+                        st = sched.slots[i]
+                        st.pos += 1
+                        self._emit(st, tok[i],
+                                   lg[i] if self.capture_logits else None)
         self.steps += 1
         if metrics is not None:
             metrics.sample_engine(self)
@@ -438,6 +479,6 @@ class ServeEngine:
         for slot, st in self.scheduler.step_evict():
             self.results[st.rid] = np.asarray(st.out, np.int32)
             if self.metrics is not None:
-                self.metrics.on_evict(st.rid)
+                self.metrics.on_evict(st)
             self._req_event("evict", st.rid, n_tokens=len(st.out))
         return self.results

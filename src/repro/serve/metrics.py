@@ -8,9 +8,9 @@ collector:
   * `Counter`   — monotonic event counts (requests, tokens, steps).
   * `Gauge`     — last-observed values (queue depth, KV occupancy).
   * `Histogram` — log-spaced buckets over a fixed range plus a bounded
-    raw-sample reservoir, so both bucket counts (cheap, exact export)
-    and true percentiles (from the reservoir) are available.  TTFT and
-    per-token latency are the headline users.
+    uniform sample of the raw observations, so both bucket counts
+    (cheap, exact export) and percentiles (from the sample) are
+    available.  TTFT and per-token latency are the headline users.
 
 `ServeMetrics` binds a registry to the `ServeEngine` lifecycle:
 enqueue -> admit (+prefill/first token) -> per-step decode -> evict,
@@ -27,6 +27,7 @@ from __future__ import annotations
 import json
 import math
 import pathlib
+import random
 import threading
 import time
 
@@ -73,11 +74,11 @@ class Histogram:
     """Log-spaced-bucket histogram with a bounded raw reservoir.
 
     Buckets span [lo, hi) in `n_buckets` equal log steps, with one
-    underflow and one overflow bucket at the ends.  The first
-    `reservoir` raw observations are kept verbatim so `percentile()` is
-    exact for short runs (a serving smoke records hundreds of samples,
-    not millions); beyond that, percentiles degrade gracefully to the
-    retained prefix while bucket counts stay exact forever.
+    underflow and one overflow bucket at the ends.  Up to `reservoir`
+    raw observations are kept, a uniform sample of all of them
+    (Algorithm R, from a fixed seed): `percentile()` is exact while the
+    count fits and estimates the whole run's percentiles beyond it,
+    while bucket counts stay exact forever.
     """
 
     def __init__(self, name: str, help: str = "", lo: float = 1e-6,
@@ -93,6 +94,7 @@ class Histogram:
         self.sum = 0.0
         self._raw: list[float] = []
         self._reservoir = int(reservoir)
+        self._rng = random.Random(0)
 
     def _bucket(self, v: float) -> int:
         if v < self.lo:
@@ -108,13 +110,17 @@ class Histogram:
         self.sum += v
         if len(self._raw) < self._reservoir:
             self._raw.append(v)
+        else:
+            j = self._rng.randrange(self.count)
+            if j < self._reservoir:
+                self._raw[j] = v
 
     def bucket_edges(self) -> list[float]:
         return [math.exp(self._log_lo + i * self._log_step)
                 for i in range(self.n_buckets + 1)]
 
     def percentile(self, q: float) -> float:
-        """q in [0, 100], from the raw reservoir (nan when empty)."""
+        """q in [0, 100], from the raw sample (nan when empty)."""
         if not self._raw:
             return math.nan
         xs = sorted(self._raw)
@@ -188,14 +194,14 @@ class ServeMetrics:
     latency is measured host-side around the forced device sync, so the
     per-token histogram records the same wall time `bench_serve.py`
     measures externally (the acceptance-criteria consistency check).
+    Admission wait, TTFT and end-to-end time run from the request's own
+    scheduler stamps (`SlotState.t_submit`, `t_admit`).
     """
 
     def __init__(self, registry: MetricsRegistry | None = None):
         r = registry if registry is not None else MetricsRegistry()
         self.registry = r
         self._profile = None
-        self._submit_t: dict[int, float] = {}
-        self._admit_t: dict[int, float] = {}
         # counters
         self.requests_submitted = r.counter(
             "serve.requests_submitted", "requests entering the queue")
@@ -246,36 +252,27 @@ class ServeMetrics:
             "serve.recovery_s", "PE-failure drain + re-queue wall time")
 
     # -- lifecycle hooks (ServeEngine calls these) ---------------------------
-    def on_submit(self, rid: int) -> None:
+    def on_submit(self) -> None:
         self.requests_submitted.inc()
-        self._submit_t[rid] = time.perf_counter()
 
-    def on_admit(self, rid: int) -> None:
-        now = time.perf_counter()
+    def on_admit(self, st) -> None:
+        """`st`: the admitted request's `SlotState`."""
         self.requests_admitted.inc()
-        self._admit_t[rid] = now
-        t0 = self._submit_t.get(rid)
-        if t0 is not None:
-            self.admission_wait_s.observe(now - t0)
+        self.admission_wait_s.observe(st.t_admit - st.t_submit)
 
-    def on_first_token(self, rid: int) -> None:
+    def on_first_token(self, st) -> None:
         self.prefill_runs.inc()
         self.tokens_generated.inc()
-        t0 = self._submit_t.get(rid)
-        if t0 is not None:
-            self.ttft_s.observe(time.perf_counter() - t0)
+        self.ttft_s.observe(time.perf_counter() - st.t_submit)
 
     def on_decode_step(self, n_active: int, wall_s: float) -> None:
         self.decode_steps.inc()
         self.tokens_generated.inc(n_active)
         self.per_token_s.observe(wall_s)
 
-    def on_evict(self, rid: int) -> None:
+    def on_evict(self, st) -> None:
         self.requests_completed.inc()
-        t0 = self._submit_t.pop(rid, None)
-        self._admit_t.pop(rid, None)
-        if t0 is not None:
-            self.e2e_s.observe(time.perf_counter() - t0)
+        self.e2e_s.observe(time.perf_counter() - st.t_submit)
 
     def on_backpressure(self) -> None:
         self.backpressure_waits.inc()

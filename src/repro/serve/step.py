@@ -42,6 +42,7 @@ def build_decode_step(cfg: ModelConfig, axes: AxisSpec, backend: str,
     return fn
 
 
+@jax.named_scope("sample")
 def sample_greedy(comm: Comm, logits):
     """Greedy sampling over vocab-sharded logits: local argmax + global
     combine over the model axis.

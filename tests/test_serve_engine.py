@@ -114,6 +114,26 @@ def test_submit_validates_against_max_pages():
         sched.submit(np.asarray([], np.int32), 4)
 
 
+def test_scheduler_stamps_submit_and_admission():
+    """Two clock reads per request: at submit and at admission, so
+    admission minus submission is the request's queue wait."""
+    from repro.serve.engine import Scheduler
+    now = [10.0]
+    pool = PagePool(SymmetricHeap((2 + 1) * PAGE_BYTES), PAGE_BYTES)
+    sched = Scheduler(PagedKV(pool, 1, 8), PAGE_TOKENS, clock=lambda: now[0])
+    sched.submit(np.arange(1, 9), 8)           # rid 0: 2 pages
+    now[0] = 10.5
+    sched.submit(np.arange(1, 9), 8)           # rid 1 waits for the slot
+    now[0] = 11.0
+    (_, st0), = sched.step_admit()
+    assert (st0.t_submit, st0.t_admit) == (10.0, 11.0)
+    st0.done = True
+    now[0] = 12.25
+    sched.step_evict()
+    (_, st1), = sched.step_admit()
+    assert st1.t_admit - st1.t_submit == 12.25 - 10.5
+
+
 # ---------------------------------------------------------------------------
 # Engine on SIM (single device): batched == alone, bitwise
 # ---------------------------------------------------------------------------
@@ -206,6 +226,55 @@ def test_engine_eos_stops_early(prompts):
     eng2.run()
     assert len(eng2.results[r2]) == stop <= 3
     assert np.array_equal(eng2.results[r2], toks[:stop])
+
+
+def test_engine_phases_on_profiler_clock(prompts, tmp_path):
+    """The engine's phases are host spans of the profiler's own trace,
+    nested under serve.step: one serve.prefill per admission, one
+    serve.decode per step with live slots."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _make_engine(max_slots=2)
+    for p in prompts[:3]:
+        eng.submit(p, 4)
+    with jax.profiler.trace(str(tmp_path)):
+        outs = []
+        while not eng.scheduler.idle():
+            outs.append(eng.step())
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    steps = [(s, e) for s, e, n in spans if n == "serve.step"]
+    by = {n: [(s, e) for s, e, m in spans if m == n]
+          for n in ("serve.evict", "serve.admit", "serve.prefill",
+                    "serve.decode.prepare", "serve.decode", "serve.emit")}
+    assert len(steps) == len(outs)
+    assert len(by["serve.prefill"]) == sum(len(o["admitted"]) for o in outs)
+    assert len(by["serve.decode"]) == sum(bool(o["decoded"]) for o in outs)
+    assert len(by["serve.evict"]) == len(by["serve.admit"]) == len(outs)
+    for name, evs in by.items():
+        for s, e in evs:
+            assert any(s0 <= s and e <= e0 for s0, e0 in steps), name
+
+
+def test_program_texts_hold_every_scope():
+    """The compiled prefill and decode programs carry the model's named
+    scopes in their instructions' metadata."""
+    import re
+
+    texts = _make_engine().program_texts()
+    assert sorted(texts) == ["decode_fn", "prefill_fn"]
+    for text in texts.values():
+        for scope in ("kv_update", "kv_gather", "attend", "attn_proj",
+                      "mlp", "lm_head", "sample"):
+            assert re.search(rf'op_name="[^"]*/{scope}/', text), scope
 
 
 # ---------------------------------------------------------------------------

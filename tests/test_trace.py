@@ -287,6 +287,19 @@ def test_histogram_buckets_and_percentiles():
     assert math.isnan(Histogram("e").percentile(50))
 
 
+def test_histogram_reservoir_samples_the_whole_run():
+    """Past the reservoir's size the kept observations stay a uniform
+    sample of all of them, not the run's first ones."""
+    from repro.serve.metrics import Histogram
+    h = Histogram("lat", reservoir=8192)
+    for i in range(20_000):
+        h.observe(0.001 if i < 10_000 else 1.0)
+    assert h.count == 20_000 and len(h._raw) == 8192
+    late = sum(v == 1.0 for v in h._raw) / len(h._raw)
+    assert 0.45 < late < 0.55
+    assert h.percentile(10) == 0.001 and h.percentile(90) == 1.0
+
+
 def test_registry_types_and_export(tmp_path):
     from repro.serve.metrics import MetricsRegistry
     r = MetricsRegistry()
@@ -306,21 +319,28 @@ def test_registry_types_and_export(tmp_path):
 
 
 def test_serve_metrics_lifecycle_math():
+    import time
+
+    from repro.serve.engine import SlotState
     from repro.serve.metrics import ServeMetrics
     m = ServeMetrics()
-    m.on_submit(0)
-    m.on_admit(0)
-    m.on_first_token(0)
+    t_submit = time.perf_counter() - 0.5
+    st = SlotState(rid=0, prompt=np.arange(3), max_new=3, pos=3,
+                   t_submit=t_submit, t_admit=t_submit + 0.25)
+    m.on_submit()
+    m.on_admit(st)
+    m.on_first_token(st)
     m.on_decode_step(1, 0.002)
     m.on_decode_step(1, 0.004)
-    m.on_evict(0)
+    m.on_evict(st)
     m.on_backpressure()
     assert m.requests_completed.value == 1
     assert m.tokens_generated.value == 3          # first + 2 decode
     assert m.ttft_s.count == 1 and m.e2e_s.count == 1
+    assert m.admission_wait_s.percentile(50) == pytest.approx(0.25)
+    assert 0.5 <= m.ttft_s.percentile(50) <= m.e2e_s.percentile(50)
     assert m.per_token_s.percentile(50) in (0.002, 0.004)
     assert m.backpressure_waits.value == 1
-    assert m._submit_t == {}                      # evict cleans up
 
 
 # ---------------------------------------------------------------------------

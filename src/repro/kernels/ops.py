@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from . import flash_attention as _fa
+from . import paged_decode as _pd
 from . import put_copy as _pc
 from . import reduce_combine as _rc
 from . import ref
@@ -189,6 +190,20 @@ def attention(q, k, v, *, causal=True, window=None, softcap=None,
     interpret = _default_interpret() if interpret is None else interpret
     return _attention(q, k, v, causal, window, softcap, sm_scale, bq, bk,
                       interpret)
+
+
+def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
+                           page_table, positions, *, page_size: int,
+                           window=None, softcap=None,
+                           interpret: bool | None = None):
+    """Paged decode attention (`paged_decode.py`): one new row per slot
+    against the pages it has filled, read in place from the stacked pool.
+    Forward-only (serving); interpret mode off a TPU."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return _pd.paged_decode_attention(
+        q, k_new, v_new, k_pool, v_pool, layer, page_table, positions,
+        page_size=page_size, window=window, softcap=softcap,
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

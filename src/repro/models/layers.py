@@ -402,27 +402,43 @@ def _cache_attend(cfg, q, ck, cv, valid, slot_map=None, comm=None,
 def paged_kv_update(pool_leaf, page_table, new, positions, page_size: int):
     """Scatter per-position rows into a paged KV pool.
 
-    pool_leaf: (num_pages, page_size, ...) — one layer's page pool;
+    pool_leaf: (num_pages, page_size, K*hd) — one layer's page pool;
     page_table: (B, max_pages) int32 physical page ids (0 = null page);
-    new: (B, L, ...) rows to write; positions: (B, L) global positions.
+    new: (B, L, K, hd) rows to write; positions: (B, L) global positions.
     Rows land at pool[page_table[b, pos // page_size], pos % page_size].
     Distinct sequences own distinct pages, so batched writes never
     collide except on the reserved null page (whose contents are never
     read through a valid mask)."""
-    B = positions.shape[0]
     page = positions // page_size
     off = positions % page_size
     phys = jnp.take_along_axis(page_table, page, axis=1)     # (B, L)
-    return pool_leaf.at[phys, off].set(new.astype(pool_leaf.dtype))
+    rows = new.reshape(new.shape[:2] + pool_leaf.shape[-1:])
+    return pool_leaf.at[phys, off].set(rows.astype(pool_leaf.dtype))
+
+
+@jax.named_scope("kv_update")
+def paged_kv_write_rows(pool_leaf, page_table, rows, positions,
+                        page_size: int):
+    """Write one decode step's new rows of every layer into a stacked
+    pool.  pool_leaf: (layers, num_pages, page_size, K*hd); rows:
+    (layers, B, K*hd); positions: (B,)."""
+    n, B = rows.shape[:2]
+    phys = page_table[jnp.arange(B), positions // page_size]
+    # every index explicit, (layers, B) each, so the scatter's window is
+    # one K*hd row and XLA updates the pool in its own layout
+    layer = jnp.broadcast_to(jnp.arange(n)[:, None], (n, B))
+    return pool_leaf.at[layer, jnp.broadcast_to(phys, (n, B)),
+                        jnp.broadcast_to(positions % page_size, (n, B))].set(
+        rows.astype(pool_leaf.dtype))
 
 
 @jax.named_scope("kv_gather")
 def paged_kv_gather(pool_leaf, page_table):
-    """Gather a sequence-contiguous (B, S_max, ...) view of each row's
+    """Gather a sequence-contiguous (B, S_max, K*hd) view of each row's
     pages (S_max = max_pages * page_size).  Invalid/unallocated table
     entries point at the null page; the attention validity mask excludes
     them."""
-    got = jnp.take(pool_leaf, page_table, axis=0)   # (B, P, ps, ...)
+    got = jnp.take(pool_leaf, page_table, axis=0)   # (B, P, ps, K*hd)
     B, P, ps = got.shape[0], got.shape[1], got.shape[2]
     return got.reshape((B, P * ps) + got.shape[3:])
 
@@ -466,18 +482,28 @@ def _attend_mq(cfg, q, ck, cv, valid, slot_map=None):
     return acc / jnp.maximum(l_den, 1e-30)
 
 
-def attention_paged(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
-                    page_table, positions, *, page_size: int,
-                    is_local_layer: bool = False):
-    """GQA attention against a paged KV pool — one code path for prefill
-    (x: (B, L, d), L = prompt bucket) and decode (L = 1).
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
-    pool: {"k","v"} (num_pages, page_size, K_local, hd); page_table:
-    (B, max_pages) physical page ids.  K/V rows for every position are
-    scattered into the owning page, then each row's pages are gathered
-    back sequence-contiguous and attended with a causal(+window) mask.
-    Sliding windows are handled purely by masking (pages keep the full
-    sequence), so paged results equal the full-length dense cache path."""
+
+def paged_decode_kernel(cfg: ModelConfig, tp: int, L: int) -> bool:
+    """Whether paged attention over L rows runs the Pallas paged-decode
+    kernel (`attention_paged_decode`): a single-token decode on a TPU
+    with the KV heads sharded over the model axis.  Prefill, the
+    replicated-KV layout and every other backend keep the gather path
+    (`attention_paged`), the reference the kernel is tested against."""
+    return L == 1 and _on_tpu() and not _gqa_dims(cfg, tp)[2]
+
+
+def _layer_window(cfg: ModelConfig, is_local_layer: bool):
+    if cfg.local_global_period is not None and is_local_layer:
+        return cfg.local_window
+    return cfg.window
+
+
+def _paged_qkv(comm: Comm, cfg: ModelConfig, p: Params, x, positions):
+    """q (B,L,Hq_local,hd), k/v (B,L,K_store,hd) and, for the replicated
+    KV layout, the one-hot q-head -> stored-KV-head map (else None)."""
     tp = comm.axis_size(comm.axes.model)
     B, L, d = x.shape
     hd = cfg.hd
@@ -498,29 +524,78 @@ def attention_paged(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
         v = jnp.take(v, sidx, axis=2)
         q2 = jnp.asarray(q2slot)[rank]                       # (nq_local,)
         slot_map = jax.nn.one_hot(q2, ndk, dtype=jnp.float32)
+    return q, k, v, slot_map
 
-    pk = paged_kv_update(pool["k"], page_table, k, positions, page_size)
-    pv = paged_kv_update(pool["v"], page_table, v, positions, page_size)
-    ck = paged_kv_gather(pk, page_table)                     # (B,S_max,K,hd)
-    cv = paged_kv_gather(pv, page_table)
 
-    S_max = ck.shape[1]
-    window = cfg.window
-    if cfg.local_global_period is not None and is_local_layer:
-        window = cfg.local_window
-    kv_pos = jnp.arange(S_max)[None, None, :]                # (1,1,S)
-    valid = kv_pos <= positions[:, :, None]
-    if window is not None:
-        valid &= kv_pos > (positions[:, :, None] - window)
-
-    out = _attend_mq(cfg, q, ck, cv, valid, slot_map)
+def _paged_out(comm: Comm, cfg: ModelConfig, p: Params, out):
+    """Ghost-head mask, output projection and the model-axis allreduce."""
+    tp = comm.axis_size(comm.axes.model)
+    B, L, nq_local, hd = out.shape
     if cfg.n_heads % tp:   # zero ghost heads
         _, valid_h = _head_ids(comm, cfg, tp)
         out = out * valid_h[None, None, :, None]
     out = out.reshape(B, L, nq_local * hd).astype(cfg.dtype)
     with jax.named_scope("attn_proj"):
         y = _dense(out, p["wo"])
-    return comm.allreduce(y, comm.axes.model), {"k": pk, "v": pv}
+    return comm.allreduce(y, comm.axes.model)
+
+
+def attention_paged(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
+                    page_table, positions, *, page_size: int,
+                    is_local_layer: bool = False):
+    """GQA attention against a paged KV pool — one code path for prefill
+    (x: (B, L, d), L = prompt bucket) and decode (L = 1) off the kernel.
+
+    pool: {"k","v"} (num_pages, page_size, K_local*hd); page_table:
+    (B, max_pages) physical page ids.  K/V rows for every position are
+    scattered into the owning page, then each row's pages are gathered
+    back sequence-contiguous and attended with a causal(+window) mask.
+    Sliding windows are handled purely by masking (pages keep the full
+    sequence), so paged results equal the full-length dense cache path."""
+    hd = cfg.hd
+    q, k, v, slot_map = _paged_qkv(comm, cfg, p, x, positions)
+    B, K = k.shape[0], k.shape[2]
+    pk = paged_kv_update(pool["k"], page_table, k, positions, page_size)
+    pv = paged_kv_update(pool["v"], page_table, v, positions, page_size)
+    ck = paged_kv_gather(pk, page_table).reshape(B, -1, K, hd)
+    cv = paged_kv_gather(pv, page_table).reshape(B, -1, K, hd)
+
+    S_max = ck.shape[1]
+    window = _layer_window(cfg, is_local_layer)
+    kv_pos = jnp.arange(S_max)[None, None, :]                # (1,1,S)
+    valid = kv_pos <= positions[:, :, None]
+    if window is not None:
+        valid &= kv_pos > (positions[:, :, None] - window)
+
+    out = _attend_mq(cfg, q, ck, cv, valid, slot_map)
+    return _paged_out(comm, cfg, p, out), {"k": pk, "v": pv}
+
+
+def attention_paged_decode(comm: Comm, cfg: ModelConfig, p: Params, x,
+                           pool, layer, page_table, positions, *,
+                           page_size: int, is_local_layer: bool = False):
+    """Single-token paged decode through the Pallas paged-decode kernel
+    (taken where `paged_decode_kernel` says).
+
+    x: (B, 1, d); pool: {"k","v"} the WHOLE stacked pool (layers,
+    num_pages, page_size, K_local*hd), read in place at `layer`: a
+    per-layer slice handed to a custom call would be materialised.  Each
+    slot reads only the pages holding its earlier positions; its new K/V
+    row is attended from registers and returned, (B, K_local*hd) each,
+    for the caller to write into the pool once every layer has run.
+    Same arithmetic as the gather path: bf16 K/V, f32 scores, softmax and
+    weighted sum."""
+    B = x.shape[0]
+    q, k, v, _ = _paged_qkv(comm, cfg, p, x, positions)
+    qf = q[:, 0].astype(jnp.float32) / math.sqrt(cfg.hd)
+    with jax.named_scope("attend"):
+        out = kops.paged_decode_attention(
+            qf, k[:, 0], v[:, 0], pool["k"], pool["v"], layer, page_table,
+            positions[:, 0], page_size=page_size,
+            window=_layer_window(cfg, is_local_layer), softcap=cfg.softcap)
+    rows = {c: a[:, 0].reshape(B, -1).astype(pool[c].dtype)
+            for c, a in (("k", k), ("v", v))}
+    return _paged_out(comm, cfg, p, out[:, None]), rows
 
 
 # ---------------------------------------------------------------------------

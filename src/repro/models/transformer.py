@@ -486,9 +486,11 @@ def paged_families() -> tuple[str, ...]:
 def init_kv_pool(cfg: ModelConfig, tp: int, num_pages: int,
                  page_size: int) -> Params:
     """Stacked per-layer-group paged KV pools: like `init_cache` but the
-    (B, S) cache dims become (num_pages, page_size) — page p of every
-    sequence lives at the SAME physical index in every layer's pool, so
-    one page table serves the whole stack."""
+    (B, S) cache dims become (num_pages, page_size) and the K heads are
+    merged into the lane dim, (layers, num_pages, page_size, K*hd), so a
+    page of one layer is one contiguous tile the decode kernel moves in
+    one DMA.  Page p of every sequence lives at the SAME physical index
+    in every layer's pool, so one page table serves the whole stack."""
     if cfg.family not in paged_families():
         raise ValueError(
             f"paged KV supports {paged_families()}, not {cfg.family!r}")
@@ -499,7 +501,8 @@ def init_kv_pool(cfg: ModelConfig, tp: int, num_pages: int,
             a[None], (n,) + a.shape).copy(), one)
 
     def one_pool():
-        return L.init_attn_cache(cfg, tp, num_pages, page_size)
+        return {c: a.reshape(num_pages, page_size, -1) for c, a in
+                L.init_attn_cache(cfg, tp, num_pages, page_size).items()}
 
     if cfg.local_global_period:
         return {"pairs_local": stack(cfg.n_layers // 2, one_pool),
@@ -507,43 +510,57 @@ def init_kv_pool(cfg: ModelConfig, tp: int, num_pages: int,
     return {"layers": stack(cfg.n_layers, one_pool)}
 
 
-def _attn_block_paged(comm, cfg, bp, x, pool, page_table, positions,
-                      page_size, is_local=False):
-    h = L.rms_norm(x, bp["ln1"])
-    a, pool = L.attention_paged(comm, cfg, bp["attn"], h, pool, page_table,
-                                positions, page_size=page_size,
-                                is_local_layer=is_local)
-    x = x + a
-    h = L.rms_norm(x, bp["ln2"])
-    return x + L.mlp(comm, cfg, bp["mlp"], h), pool
-
-
 def _paged_stack(comm, cfg, params, pool, page_table, x, positions,
                  page_size):
     """Run the layer stack against paged KV pools.  One code path for
     prefill (L = prompt bucket) and decode (L = 1): identical traced ops
     per row is what makes the engine's batched-vs-alone decode tokens
-    bit-identical (DESIGN.md §15)."""
+    bit-identical (DESIGN.md §15).
+
+    Gather path: each layer's pool is a scan input and output, scattered
+    into and gathered from.  Kernel path (`L.paged_decode_kernel`): the
+    stacked pools stay whole as constants of the scan, each layer's
+    kernel reads its pages in place by layer index, and the layers' new
+    K/V rows leave the scan as outputs, written into the pools in one
+    scatter each after it."""
+    kernel = L.paged_decode_kernel(cfg, _tp(comm), x.shape[1])
     if cfg.local_global_period:
-        def pair(x, ps):
-            bp_l, bp_g, p_l, p_g = ps
-            x, p_l = _attn_block_paged(comm, cfg, bp_l, x, p_l, page_table,
-                                       positions, page_size, is_local=True)
-            x, p_g = _attn_block_paged(comm, cfg, bp_g, x, p_g, page_table,
-                                       positions, page_size)
-            return x, (p_l, p_g)
-        x, (pl, pg) = _scan(cfg, pair, x,
-                            (params["pairs"]["local"],
-                             params["pairs"]["global"],
-                             pool["pairs_local"], pool["pairs_global"]))
-        return x, {"pairs_local": pl, "pairs_global": pg}
-    def step(x, bc):
-        bp, pl = bc
-        x, pl = _attn_block_paged(comm, cfg, bp, x, pl, page_table,
-                                  positions, page_size)
-        return x, pl
-    x, np_ = _scan(cfg, step, x, (params["layers"], pool["layers"]))
-    return x, {"layers": np_}
+        names, local = ("pairs_local", "pairs_global"), (True, False)
+        bps = (params["pairs"]["local"], params["pairs"]["global"])
+    else:
+        names, local, bps = ("layers",), (False,), (params["layers"],)
+
+    def block(x, bp, kv, name, is_local):
+        h = L.rms_norm(x, bp["ln1"])
+        if kernel:                              # kv: this layer's index
+            a, kv = L.attention_paged_decode(
+                comm, cfg, bp["attn"], h, pool[name], kv, page_table,
+                positions, page_size=page_size, is_local_layer=is_local)
+        else:                                   # kv: this layer's pool
+            a, kv = L.attention_paged(
+                comm, cfg, bp["attn"], h, kv, page_table, positions,
+                page_size=page_size, is_local_layer=is_local)
+        x = x + a
+        h = L.rms_norm(x, bp["ln2"])
+        return x + L.mlp(comm, cfg, bp["mlp"], h), kv
+
+    def step(x, xs):
+        outs = []
+        for bp, kv, name, is_local in zip(*xs, names, local):
+            x, kv = block(x, bp, kv, name, is_local)
+            outs.append(kv)
+        return x, outs
+
+    n = jax.tree.leaves(bps[0])[0].shape[0]
+    kvs = [jnp.arange(n, dtype=jnp.int32) if kernel else pool[name]
+           for name in names]
+    x, outs = _scan(cfg, step, x, (bps, kvs))
+    if kernel:
+        outs = [{c: L.paged_kv_write_rows(pool[name][c], page_table,
+                                          rows[c], positions[:, 0],
+                                          page_size)
+                 for c in ("k", "v")} for name, rows in zip(names, outs)]
+    return x, dict(zip(names, outs))
 
 
 def prefill_paged(comm: Comm, cfg: ModelConfig, params: Params, pool: Params,

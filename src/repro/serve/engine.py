@@ -18,7 +18,10 @@ Three pieces, separable on purpose:
     pages) plus a fixed-shape batched decode step over all slots.
     Inactive slots ride along masked (their page-table rows point at the
     reserved null page), so the decode step never recompiles as
-    sequences join and leave.  Every per-row op is batch-independent, so
+    sequences join and leave.  On a TPU the decode's attention reads
+    each slot's live pages in place through the paged-decode kernel
+    (DESIGN.md §15); its `serve.decode` span names the path and the
+    pages one layer read.  Every per-row op is batch-independent, so
     a request's greedy tokens are bit-identical whether it runs alone or
     joins mid-batch — the engine's core correctness invariant.
 
@@ -143,6 +146,63 @@ class Scheduler:
         return not self.queue and all(s is None for s in self.slots)
 
 
+def serve_programs(cfg, mesh, *, page_size: int, backend: str = "shmem",
+                   **comm_kw):
+    """The engine's jitted (prefill, decode) programs over `mesh` and the
+    KV pool's partition specs.  Built from shapes alone, so they also
+    lower for a described chip.
+
+    prefill(params, pool, table (1, max_pages), tokens (1, Lb), positions
+    (1, Lb), last_idx (1,)) and decode(params, pool, table (B, max_pages),
+    tokens (B, 1), positions (B,)) each return (greedy tokens, f32
+    logits, pool).  Decode donates the pool: the kernel path writes the
+    step's new rows into it in place."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from ..launch import build
+    from ..models import transformer
+    from ..parallel.comm import Comm
+    from . import step as sstep
+
+    axes = build.axis_spec(mesh)
+    _, tp, _ = build.mesh_dims(mesh)
+    _, pspecs = build.abstract_params(cfg, mesh)
+    poolspecs = jax.tree.map(
+        lambda _: P(None, None, None, "model"),
+        jax.eval_shape(lambda: transformer.init_kv_pool(cfg, tp, 1,
+                                                        page_size)))
+
+    def prefill_fn(params, pool, table, tokens, positions, last_idx):
+        comm = Comm(axes, backend, **comm_kw)
+        logits, pool = transformer.prefill_paged(
+            comm, cfg, params, pool, table, tokens, positions,
+            page_size=page_size)
+        lg = jnp.take_along_axis(
+            logits, last_idx[:, None, None], axis=1)[:, 0]
+        tok = sstep.sample_greedy(comm, lg)
+        return tok, lg, pool
+
+    def decode_fn(params, pool, table, tokens, positions):
+        comm = Comm(axes, backend, **comm_kw)
+        logits, pool = transformer.decode_step_paged(
+            comm, cfg, params, pool, table, tokens, positions,
+            page_size=page_size)
+        lg = logits[:, 0]
+        tok = sstep.sample_greedy(comm, lg)
+        return tok, lg, pool
+
+    lg_spec = P(None, "model")
+    pjit = jax.jit(build.shard_mapped(
+        prefill_fn, mesh, (pspecs, poolspecs, P(), P(), P(), P()),
+        (P(), lg_spec, poolspecs)))
+    djit = jax.jit(build.shard_mapped(
+        decode_fn, mesh, (pspecs, poolspecs, P(), P(), P()),
+        (P(), lg_spec, poolspecs)), donate_argnums=1)
+    return pjit, djit, poolspecs
+
+
 class ServeEngine:
     """Continuous-batching engine: paged prefill + fixed-shape batched
     decode over `max_slots` sequences, greedy sampling through the
@@ -164,12 +224,9 @@ class ServeEngine:
 
         import jax
         import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
 
         from ..launch import build
-        from ..models import transformer
-        from ..parallel.comm import Comm
-        from . import step as sstep
+        from ..models import layers, transformer
 
         cfg = dc.replace(cfg, fsdp=False)
         if cfg.family not in transformer.paged_families():
@@ -220,56 +277,23 @@ class ServeEngine:
         from ..core.trace import Tracer
         self._trace = profile if isinstance(profile, Tracer) else None
 
-        axes = build.axis_spec(mesh)
         comm_kw = dict(allreduce_algo=allreduce_algo, topo=topo, link=link,
                        embedding=embedding, tuner=tuner, profile=profile)
         n_dev_pages = pool.num_pages
 
         with jax.set_mesh(mesh):
-            init_fn, pshapes, pspecs = build.make_init_fn(cfg, mesh, backend)
+            init_fn, _, _ = build.make_init_fn(cfg, mesh, backend)
             if params is None:
                 params = jax.jit(init_fn)(jax.random.key(init_key))
             self.params = params
-            self._pspecs = pspecs
-
-            pool_struct = jax.eval_shape(lambda: transformer.init_kv_pool(
-                cfg, tp, n_dev_pages, page_size))
-            poolspecs = jax.tree.map(
-                lambda _: P(None, None, None, "model", None), pool_struct)
-            self._poolspecs = poolspecs
+            self._pjit, self._djit, poolspecs = serve_programs(
+                cfg, mesh, page_size=page_size, backend=backend, **comm_kw)
             self.pool = jax.jit(build.shard_mapped(
                 lambda: transformer.init_kv_pool(cfg, tp, n_dev_pages,
                                                  page_size),
                 mesh, (), poolspecs))()
-
-            def prefill_fn(params, pool, table, tokens, positions, last_idx):
-                comm = Comm(axes, backend, **comm_kw)
-                logits, pool = transformer.prefill_paged(
-                    comm, cfg, params, pool, table, tokens, positions,
-                    page_size=page_size)
-                lg = jnp.take_along_axis(
-                    logits, last_idx[:, None, None], axis=1)[:, 0]
-                tok = sstep.sample_greedy(comm, lg)
-                return tok, lg, pool
-
-            def decode_fn(params, pool, table, tokens, positions):
-                comm = Comm(axes, backend, **comm_kw)
-                logits, pool = transformer.decode_step_paged(
-                    comm, cfg, params, pool, table, tokens, positions,
-                    page_size=page_size)
-                lg = logits[:, 0]
-                tok = sstep.sample_greedy(comm, lg)
-                return tok, lg, pool
-
-            lg_spec = P(None, "model")
-            self._pjit = jax.jit(build.shard_mapped(
-                prefill_fn, mesh,
-                (pspecs, poolspecs, P(), P(), P(), P()),
-                (P(), lg_spec, poolspecs)))
-            self._djit = jax.jit(build.shard_mapped(
-                decode_fn, mesh,
-                (pspecs, poolspecs, P(), P(), P()),
-                (P(), lg_spec, poolspecs)))
+        self.decode_path = ("kernel" if layers.paged_decode_kernel(
+            cfg, tp, 1) else "gather")
 
     # -- observability helpers ------------------------------------------------
     @contextlib.contextmanager
@@ -279,7 +303,7 @@ class ServeEngine:
         profiler's clock beside the device's operations and costs about
         a microsecond when no profiler session is open; also a nested
         tracer span or a bare profiler op when one is attached."""
-        with self._jax.profiler.TraceAnnotation(name):
+        with self._jax.profiler.TraceAnnotation(name, **meta):
             if self._trace is not None and self._trace.enabled:
                 with self._trace.span(name, **meta):
                     yield
@@ -288,6 +312,16 @@ class ServeEngine:
                     yield
             else:
                 yield
+
+    def _kv_pages(self, positions) -> int:
+        """Pages one attention layer of this decode reads: on the kernel
+        path the pages holding each slot's earlier positions (a windowed
+        layer reads fewer), on the gather path every slot's whole table."""
+        from ..kernels.paged_decode import page_span
+
+        if self.decode_path == "gather":
+            return self.kv.table.size
+        return int(page_span(positions, self.page_size)[1].sum())
 
     def program_texts(self) -> dict[str, str]:
         """Compiled HLO text of the prefill and decode programs at this
@@ -449,13 +483,16 @@ class ServeEngine:
                         poss[i] = st.pos
                     args = (jnp.asarray(self.kv.table), jnp.asarray(toks),
                             jnp.asarray(poss))
-                with self._span("serve.decode", n_pes=len(active)):
+                with self._span("serve.decode", n_pes=len(active),
+                                path=self.decode_path,
+                                kv_pages=self._kv_pages(poss)):
                     tok, lg, self.pool = self._djit(
                         self.params, self.pool, *args)
                     tok = np.asarray(tok)      # force sync: step complete
                 if metrics is not None:
                     metrics.on_decode_step(len(active),
-                                           time.perf_counter() - t0)
+                                           time.perf_counter() - t0,
+                                           self.decode_path)
                 with self._span("serve.emit"):
                     lg = np.asarray(lg) if self.capture_logits else None
                     for i in active:

@@ -215,6 +215,11 @@ class ServeMetrics:
             "serve.prefill_runs", "paged prefill forward passes")
         self.decode_steps = r.counter(
             "serve.decode_steps", "batched decode steps executed")
+        self.decode_steps_by_path = {
+            path: r.counter(f"serve.decode_steps.{path}",
+                            f"decode steps whose attention took the "
+                            f"{path} path")
+            for path in ("kernel", "gather")}
         self.backpressure_waits = r.counter(
             "serve.backpressure_waits",
             "engine steps where the queue head could not get pages")
@@ -265,8 +270,12 @@ class ServeMetrics:
         self.tokens_generated.inc()
         self.ttft_s.observe(time.perf_counter() - st.t_submit)
 
-    def on_decode_step(self, n_active: int, wall_s: float) -> None:
+    def on_decode_step(self, n_active: int, wall_s: float,
+                       path: str = "gather") -> None:
+        """`path`: how the step's paged attention read the KV pool,
+        "kernel" (the paged-decode kernel) or "gather"."""
         self.decode_steps.inc()
+        self.decode_steps_by_path[path].inc()
         self.tokens_generated.inc(n_active)
         self.per_token_s.observe(wall_s)
 

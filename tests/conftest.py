@@ -94,3 +94,25 @@ except ImportError:
     _hyp.__is_repro_shim__ = True
     sys.modules["hypothesis"] = _hyp
     sys.modules["hypothesis.strategies"] = _st
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def on_kernel(monkeypatch):
+    """Take the paged decode through the Pallas paged-decode kernel, as on
+    a TPU (interpret mode here), and check that it was traced."""
+    from repro.kernels import ops as kops
+    from repro.models import layers
+
+    calls = []
+    real = kops.paged_decode_attention
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+    monkeypatch.setattr(layers, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kops, "paged_decode_attention", counted)
+    yield
+    assert calls, "the paged decode never reached the kernel"

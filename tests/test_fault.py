@@ -523,6 +523,11 @@ def test_serve_pe_failure_drains_requeues_and_regenerates_bitwise():
         assert np.array_equal(eng.results[rid], ref.results[q]), rid
 
 
+def test_serve_pe_failure_drains_requeues_and_regenerates_bitwise_on_kernel(
+        on_kernel):
+    test_serve_pe_failure_drains_requeues_and_regenerates_bitwise()
+
+
 # ---------------------------------------------------------------------------
 # tp=2 SPMD kill-and-resume (subprocess)
 # ---------------------------------------------------------------------------

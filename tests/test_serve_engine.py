@@ -194,6 +194,75 @@ def test_engine_mid_batch_join_is_bitwise_transparent(prompts):
     assert np.array_equal(eng.results[r0], ref.results[q0])
 
 
+def test_engine_batched_equals_alone_bitwise_on_kernel(prompts, on_kernel):
+    test_engine_batched_equals_alone_bitwise(prompts)
+
+
+def test_engine_mid_batch_join_is_bitwise_transparent_on_kernel(prompts,
+                                                                on_kernel):
+    test_engine_mid_batch_join_is_bitwise_transparent(prompts)
+
+
+def test_engine_kernel_path_tokens_match_gather_path(prompts, on_kernel,
+                                                     monkeypatch):
+    """The kernel's tokens and logits match the gather path's: same bf16
+    K/V, f32 scores, softmax and weighted sum; only the f32 summation
+    order differs."""
+    from repro.models import layers
+
+    eng = _make_engine()
+    assert eng.decode_path == "kernel"
+    rids = [eng.submit(p, 6) for p in prompts]
+    eng.run()
+    monkeypatch.setattr(layers, "_on_tpu", lambda: False)
+    ref = _make_engine(params=eng.params)
+    assert ref.decode_path == "gather"
+    qids = [ref.submit(p, 6) for p in prompts]
+    ref.run()
+    for r, q in zip(rids, qids):
+        assert np.array_equal(eng.results[r], ref.results[q]), r
+        for a, b in zip(eng.logits_trace[r], ref.logits_trace[q]):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_engine_decode_span_names_path_and_pages(prompts, tmp_path,
+                                                 on_kernel):
+    """Each serve.decode span says which path the attention took and how
+    many KV pages one layer read: the pages holding each live slot's
+    earlier positions.  ServeMetrics counts the decode steps by path."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from repro.serve.metrics import ServeMetrics
+
+    eng = _make_engine(metrics=ServeMetrics())
+    for p in prompts[:2]:
+        eng.submit(p, 4)
+    want = []
+    real = eng._djit
+
+    def seen(params, pool, table, tokens, positions):
+        want.append(int(np.sum(-(-np.asarray(positions) // eng.page_size))))
+        return real(params, pool, table, tokens, positions)
+    eng._djit = seen
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    stats = [dict(e.stats)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name == "serve.decode"]
+    assert [s["path"] for s in stats] == ["kernel"] * len(want)
+    assert [s["kv_pages"] for s in stats] == want
+    assert want[0] == 1 + 2                   # positions 5 and 9, pages of 8
+    m = eng.metrics
+    assert m.decode_steps_by_path["kernel"].value == len(want)
+    assert m.decode_steps_by_path["gather"].value == 0
+
+
 def test_engine_heap_backpressure_still_serves_everyone(prompts):
     # heap sized for ~one worst-case sequence: requests serialize through
     # admission backpressure but all finish, and nothing leaks
@@ -369,3 +438,61 @@ def test_spmd_engine_and_tiebreak():
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     assert "TIE-OK" in r.stdout and "SPMD-ENGINE-OK" in r.stdout
+
+
+SPMD_KERNEL_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    import dataclasses
+    import numpy as np
+    from repro.configs import smoke_config
+    from repro.launch.mesh import make_mesh
+    from repro.models import layers
+    from repro.serve.engine import ServeEngine
+
+    # two KV heads over a model axis of 2: one per chip, not replicated,
+    # so the kernel reads each chip's half of the pool's lanes
+    cfg = dataclasses.replace(smoke_config("qwen2-0.5b"), n_heads=4,
+                              n_kv_heads=2)
+    mesh = make_mesh(1, 2)
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 100, size=n).astype(np.int32)
+               for n in (5, 9, 3)]
+    kw = dict(max_slots=3, page_size=8, max_seq=32, prompt_bucket=16,
+              capture_logits=True)
+
+    def serve(kernel, params, one_at_a_time=False):
+        layers._on_tpu = lambda: kernel
+        eng = ServeEngine(cfg, mesh, params=params, **kw)
+        assert eng.decode_path == ("kernel" if kernel else "gather")
+        out = []
+        for p in prompts:
+            out.append(eng.submit(p, 5))
+            if one_at_a_time:
+                eng.run()
+        eng.run()
+        return eng, [(eng.results[r], eng.logits_trace[r]) for r in out]
+
+    eng, batched = serve(True, None)
+    _, alone = serve(True, eng.params, one_at_a_time=True)
+    _, gather = serve(False, eng.params)
+    for (t, lg), (ta, lga), (tg, lgg) in zip(batched, alone, gather):
+        assert np.array_equal(t, ta) and np.array_equal(t, tg)
+        for a, b, c in zip(lg, lga, lgg):
+            assert np.array_equal(a, b)
+            np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-4)
+    print("SPMD-KERNEL-OK")
+""")
+
+
+def test_spmd_engine_on_kernel():
+    """tp=2 with the KV heads sharded: the kernel path is bitwise the
+    same batched and alone, and serves the gather path's tokens."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", SPMD_KERNEL_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=900)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    assert "SPMD-KERNEL-OK" in r.stdout

@@ -6,15 +6,20 @@ kernel may use.  Each test lowers one kernel at real widths for one chip
 of a described ``v5e:2x2`` host and checks that the compiled program
 holds the Mosaic kernel.  Nothing runs, so nothing here is a timing.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import flash_attention as fa
 from repro.kernels import fused_update as fu
+from repro.kernels import paged_decode as pd
 from repro.kernels import put_copy as pc
 from repro.kernels import reduce_combine as rc
 from repro.kernels import ring_attention as ra
@@ -99,3 +104,64 @@ def test_fused_adam_update_4mib(spec):
         [g0, g1], p, m, v, wd, c1, c2, lr=3e-4, b1=0.9, b2=0.95, eps=1e-8,
         wd_coef=0.1, scale=4.0, out_dtype=jnp.float32),
         f32, f32, f32, f32, f32, spec((n,), jnp.int8), scalar, scalar)
+
+
+# qwen2-serve-chat: 128 slots x 128 pages of 16 positions, 24 layers, two
+# KV heads of 64 merged into 128 lanes, plus the null page
+SLOTS, PAGES, PAGE, LAYERS = 128, 128, 16, 24
+POOL_PAGES = SLOTS * PAGES + 1
+
+
+def test_paged_decode_kernel_cell_widths(spec):
+    pool = spec((LAYERS, POOL_PAGES, PAGE, 128), jnp.bfloat16)
+    new = spec((SLOTS, 2, 64), jnp.bfloat16)
+    _compile(lambda q, kn, vn, kp, vp, layer, table, pos:
+             pd.paged_decode_attention(q, kn, vn, kp, vp, layer, table, pos,
+                                       page_size=PAGE),
+             spec((SLOTS, 14, 64), jnp.float32), new, new, pool, pool,
+             spec((), jnp.int32), spec((SLOTS, PAGES), jnp.int32),
+             spec((SLOTS,), jnp.int32))
+
+
+def test_paged_decode_program_updates_pool_in_place(topo, monkeypatch):
+    """The serving engine's decode program at the cell's size, on the
+    kernel path: the pool is donated and aliased to the output, and no
+    instruction makes a copy of it or a layer-sized slice of it."""
+    from repro.configs import get_config
+    from repro.kernels import ops as kops
+    from repro.launch import build
+    from repro.models import layers
+    from repro.serve.engine import serve_programs
+
+    monkeypatch.setattr(layers, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kops, "_default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("qwen2-0.5b"), fsdp=False)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    _, decode, poolspecs = serve_programs(cfg, mesh, page_size=PAGE)
+    shapes, pspecs = build.abstract_params(cfg, mesh)
+
+    def on_mesh(s, sp):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                    sharding=NamedSharding(mesh, sp))
+    params = jax.tree.map(on_mesh, build.global_shape(shapes, pspecs, mesh),
+                          pspecs)
+    pool_leaf = jax.ShapeDtypeStruct((LAYERS, POOL_PAGES, PAGE, 128),
+                                     jnp.bfloat16)
+    pool = jax.tree.map(lambda sp: on_mesh(pool_leaf, sp), poolspecs)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32,
+                                    sharding=NamedSharding(mesh, P()))
+    with jax.set_mesh(mesh):
+        compiled = decode.lower(params, pool, i32(SLOTS, PAGES),
+                                i32(SLOTS, 1), i32(SLOTS)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    pool_bytes = 2 * LAYERS * POOL_PAGES * PAGE * 128 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    layer_elems = POOL_PAGES * PAGE * 128
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\][^ ]* (\S+)\(", text):
+        n = int(np.prod([int(d) for d in m.group(2).split(",")]))
+        op = m.group(3)
+        assert n != layer_elems, m.group(0)          # no per-layer slice
+        assert not (n >= layer_elems and op.startswith("copy")), m.group(0)

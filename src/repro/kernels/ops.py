@@ -195,15 +195,17 @@ def attention(q, k, v, *, causal=True, window=None, softcap=None,
 def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
                            page_table, positions, *, page_size: int,
                            window=None, softcap=None,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           v_lanes: int | None = None):
     """Paged decode attention (`paged_decode.py`): one new row per slot
-    against the pages it has filled, read in place from the stacked pool.
-    Forward-only (serving); interpret mode off a TPU."""
+    against the pages it has filled, read in place from the stacked pool;
+    `v_lanes` selects the latent mode (MLA).  Forward-only (serving);
+    interpret mode off a TPU."""
     interpret = _default_interpret() if interpret is None else interpret
     return _pd.paged_decode_attention(
         q, k_new, v_new, k_pool, v_pool, layer, page_table, positions,
         page_size=page_size, window=window, softcap=softcap,
-        interpret=interpret)
+        interpret=interpret, v_lanes=v_lanes)
 
 
 # ---------------------------------------------------------------------------

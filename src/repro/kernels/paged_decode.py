@@ -27,6 +27,11 @@ elsewhere, so a single (Hq, K*hd) x (K*hd, T) product scores every query
 head against its own KV head (the zero lanes add exact zeros).  The
 output comes back in the same layout; `paged_decode_attention` picks each
 head's own lanes.
+
+Latent mode (MLA's absorbed decode, `v_lanes`): the pool holds one latent
+row [c_kv | k_rope] a position, (layers, pages, page_size, C), and every
+query head scores against that one "head"; a key's value is its first
+`v_lanes` lanes, so each page moves in one DMA and there is no V pool.
 """
 from __future__ import annotations
 
@@ -55,10 +60,16 @@ def page_span(positions, page_size: int, window: int | None = None):
 
 
 def _kernel(layer_ref, first_ref, count_ref, next_ref, table_ref, pos_ref,
-            q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, ksem, vsem, buf_ref, *, page_size: int,
-            pages_per_block: int, max_pages: int, window: int | None,
-            softcap: float | None):
+            *refs, page_size: int, pages_per_block: int, max_pages: int,
+            window: int | None, softcap: float | None,
+            v_lanes: int | None):
+    if v_lanes is None:
+        (q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
+         kbuf, vbuf, ksem, vsem, buf_ref) = refs
+        streams = ((k_hbm, kbuf, ksem), (v_hbm, vbuf, vsem))
+    else:                         # latent: values are the keys' first lanes
+        q_ref, kn_ref, k_hbm, o_ref, kbuf, ksem, buf_ref = refs
+        streams = ((k_hbm, kbuf, ksem),)
     b = pl.program_id(0)
     n_slots = pl.num_programs(0)
     P = pages_per_block
@@ -71,8 +82,7 @@ def _kernel(layer_ref, first_ref, count_ref, next_ref, table_ref, pos_ref,
             @pl.when(j * P + i < count_ref[s])
             def _():
                 page = table_ref[s * max_pages + first_ref[s] + j * P + i]
-                for hbm, vmem, sem in ((k_hbm, kbuf, ksem),
-                                       (v_hbm, vbuf, vsem)):
+                for hbm, vmem, sem in streams:
                     cp = pltpu.make_async_copy(hbm.at[layer, page],
                                                vmem.at[buf, i], sem.at[buf])
                     cp.start() if act == "start" else cp.wait()
@@ -89,7 +99,8 @@ def _kernel(layer_ref, first_ref, count_ref, next_ref, table_ref, pos_ref,
     pos = pos_ref[b]
     q = q_ref[...]                                       # (Hp, C) f32
     kn = kn_ref[...].astype(jnp.float32)                 # (1, C)
-    vn = vn_ref[...].astype(jnp.float32)
+    vn = (vn_ref[...].astype(jnp.float32) if v_lanes is None
+          else kn[:, :v_lanes])
 
     def cap(s):
         return s if softcap is None else softcap * jnp.tanh(s / softcap)
@@ -97,7 +108,7 @@ def _kernel(layer_ref, first_ref, count_ref, next_ref, table_ref, pos_ref,
     # the slot's own row starts the running softmax: m = its logit, l = 1
     m0 = cap(jnp.sum(q * kn, axis=1, keepdims=True))     # (Hp, 1)
     l0 = jnp.ones_like(m0)
-    acc0 = jnp.broadcast_to(vn, q.shape)
+    acc0 = jnp.broadcast_to(vn, (q.shape[0], vn.shape[1]))
     n_blocks = (count_ref[b] + P - 1) // P
     T = P * page_size
 
@@ -115,7 +126,8 @@ def _kernel(layer_ref, first_ref, count_ref, next_ref, table_ref, pos_ref,
         page_copies(b, j, buf, "wait")
         buf_ref[0] = 1 - buf
         k = kbuf[buf].astype(jnp.float32).reshape(T, -1)  # (T, C)
-        v = vbuf[buf].astype(jnp.float32).reshape(T, -1)
+        v = (vbuf[buf].astype(jnp.float32).reshape(T, -1) if v_lanes is None
+             else k[:, :v_lanes])
         k_pos = (first_ref[b] + j * P) * page_size
         valid = k_pos + lax.broadcasted_iota(jnp.int32, (1, T), 1) < pos
         valid_rows = k_pos + lax.broadcasted_iota(jnp.int32, (T, 1), 0) < pos
@@ -146,7 +158,8 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
                            window: int | None = None,
                            softcap: float | None = None,
                            pages_per_block: int = PAGES_PER_BLOCK,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           v_lanes: int | None = None):
     """Decode attention of one new row per slot against its paged KV.
 
     q: (B, Hq, hd) float32, already scaled by 1/sqrt(hd); k_new, v_new:
@@ -155,7 +168,18 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
     page_size, K * hd); layer: int32 scalar; page_table: (B, max_pages)
     int32; positions: (B,) int32, the new row's position.  Keys are the
     pool's positions [max(0, pos - window + 1), pos) of `layer` plus the
-    new row.  Returns (B, Hq, hd) float32."""
+    new row.  Returns (B, Hq, hd) float32.
+
+    Latent mode (`v_lanes`, MLA's absorbed decode): one KV "head" of C
+    lanes, k_pool the latent pool (layers, pages, page_size, C) and k_new
+    (B, 1, C) the new latent row; v_new and v_pool are None.  A key's
+    value is its first `v_lanes` lanes, read from the same page, so each
+    page moves in one DMA.  q: (B, Hq, C); returns (B, Hq, v_lanes)."""
+    if v_lanes is not None:
+        return _latent(q, k_new, k_pool, layer, page_table, positions,
+                       page_size=page_size, window=window, softcap=softcap,
+                       pages_per_block=pages_per_block, interpret=interpret,
+                       v_lanes=v_lanes)
     B, Hq, hd = q.shape
     C = k_pool.shape[-1]
     K = C // hd
@@ -169,11 +193,6 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
     kn = k_new.astype(k_pool.dtype).reshape(B, 1, C)
     vn = v_new.astype(v_pool.dtype).reshape(B, 1, C)
 
-    first, count = page_span(positions, page_size, window)
-    # next_slot[b]: the first slot >= b with pages to read (B if none)
-    idx = jnp.where(count > 0, jnp.arange(B, dtype=jnp.int32), B)
-    next_slot = jnp.append(lax.cummin(idx, reverse=True), B)
-
     row = pl.BlockSpec((None, Hp, C), lambda b, *_: (b, 0, 0))
     new = pl.BlockSpec((None, 1, C), lambda b, *_: (b, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -182,7 +201,7 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
         functools.partial(_kernel, page_size=page_size,
                           pages_per_block=pages_per_block,
                           max_pages=max_pages, window=window,
-                          softcap=softcap),
+                          softcap=softcap, v_lanes=None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6, grid=(B,),
             in_specs=[row, new, new, hbm, hbm], out_specs=row,
@@ -194,11 +213,59 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), first.astype(jnp.int32),
-      count.astype(jnp.int32), next_slot.astype(jnp.int32),
-      page_table.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32),
+    )(*_scalars(layer, page_table, positions, page_size, window),
       q_bd, kn, vn, k_pool, v_pool)
     # each query head keeps the lanes of its own KV head
     out = out[:, :Hq].reshape(B, K, G, K, hd)
     return jnp.stack([out[:, g, :, g] for g in range(K)], axis=1) \
         .reshape(B, Hq, hd)
+
+
+def _scalars(layer, page_table, positions, page_size, window):
+    """The scalar-prefetch operands: layer, first page and page count of
+    each slot, the next slot with pages to read, the flat table and the
+    positions."""
+    B = positions.shape[0]
+    first, count = page_span(positions, page_size, window)
+    # next_slot[b]: the first slot >= b with pages to read (B if none)
+    idx = jnp.where(count > 0, jnp.arange(B, dtype=jnp.int32), B)
+    next_slot = jnp.append(lax.cummin(idx, reverse=True), B)
+    return (jnp.reshape(layer, (1,)).astype(jnp.int32),
+            first.astype(jnp.int32), count.astype(jnp.int32),
+            next_slot.astype(jnp.int32),
+            page_table.reshape(-1).astype(jnp.int32),
+            positions.astype(jnp.int32))
+
+
+def _latent(q, k_new, pool, layer, page_table, positions, *, page_size,
+            window, softcap, pages_per_block, interpret, v_lanes):
+    """The latent mode of `paged_decode_attention`: q (B, Hq, C) against
+    one KV head of C lanes whose values are its first v_lanes lanes."""
+    B, Hq, C = q.shape
+    Hp = -(-Hq // 8) * 8
+    max_pages = page_table.shape[1]
+    q = jnp.pad(q, ((0, 0), (0, Hp - Hq), (0, 0)))
+    kn = k_new.astype(pool.dtype).reshape(B, 1, C)
+    out = pl.pallas_call(
+        functools.partial(_kernel, page_size=page_size,
+                          pages_per_block=pages_per_block,
+                          max_pages=max_pages, window=window,
+                          softcap=softcap, v_lanes=v_lanes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(B,),
+            in_specs=[pl.BlockSpec((None, Hp, C), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec((None, 1, C), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, Hp, v_lanes),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages_per_block, page_size, C), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, v_lanes), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode_attention_latent",
+    )(*_scalars(layer, page_table, positions, page_size, window), q, kn,
+      pool)
+    return out[:, :Hq]

@@ -6,8 +6,9 @@ Batch mode (default) submits every request up front and drains; with
 earlier ones decode, exercising per-step join/evict.  Both use the
 paged prefill fast-path (ONE forward pass over the prompt bucket fills
 the KV pages) instead of the seed launcher's teacher-forced per-token
-decode loop.  Families without attention KV caches (ssm/hybrid/moe)
-fall back to the dense-cache decode loop.
+decode loop.  Models the paged path does not run (ssm/hybrid, MoE
+without MLA; `transformer.paged_supported`) fall back to the dense-cache
+decode loop.
 
   python -m repro.launch.serve --arch qwen2-0.5b --smoke --tokens 16
   python -m repro.launch.serve --arch qwen2-0.5b --smoke --continuous \\
@@ -133,7 +134,7 @@ def main(argv=None):
         raise SystemExit("encoder-only arch has no decode loop")
     mesh = make_mesh(args.data, args.model)
 
-    paged_ok = (cfg.family in transformer.paged_families()
+    paged_ok = (transformer.paged_supported(cfg)
                 and args.data == 1 and args.comm == "shmem")
     if not paged_ok:
         return _legacy_decode_loop(cfg, mesh, args)

@@ -26,6 +26,21 @@ class MoEConfig:
     router_noise: float = 0.0
     capacity_factor: float = 1.25
     ep_over_data: bool = False   # EP group = (data x model) instead of model
+    # routing (layers.route): scores softmax | sigmoid; selection over
+    # score + correction bias (a router leaf when `correction_bias`),
+    # restricted to the `topk_group` best of `n_group` expert groups (a
+    # group scores the sum of its top 2); gates are the selected scores
+    # normalised to sum 1, times `routed_scale`
+    score_func: str = "softmax"
+    correction_bias: bool = False
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    # the expert share a serving chip holds (layers.moe_held): experts
+    # [experts_offset, experts_offset + experts_held) of n_experts; None
+    # holds them all
+    experts_held: int | None = None
+    experts_offset: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,6 +50,20 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071): frequencies ramped from
+    original to 1/factor between the `beta_fast` and `beta_slow`
+    rotation counts of `original_max_pos`, and an attention-scale factor
+    0.1 * mscale * ln(factor) + 1."""
+    factor: float
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_max_pos: int = 4096
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +97,7 @@ class ModelConfig:
     softcap: float | None = None          # attention logit softcap
     final_softcap: float | None = None    # lm-head logit softcap
     rope_theta: float = 10000.0
+    yarn: YarnConfig | None = None       # rope scaling of the MLA rope lanes
     mla: MLAConfig | None = None
     # MoE / SSM / hybrid
     moe: MoEConfig | None = None

@@ -24,6 +24,7 @@ import numpy as np
 from jax import lax
 
 from ..kernels import ops as kops
+from ..kernels import ref as kref
 from ..parallel.comm import Comm
 from .config import ModelConfig
 
@@ -41,11 +42,13 @@ def rms_norm(x, w, eps: float = 1e-6):
             ).astype(x.dtype)
 
 
-def rope(x, positions, theta: float):
-    """x: (..., L, H, D) with D even; positions: (..., L)."""
+def rope(x, positions, theta: float, inv_freq=None):
+    """x: (..., L, H, D) with D even; positions: (..., L).  Rotate-half
+    form; `inv_freq` (D/2,) replaces theta's plain frequencies."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = (1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+             if inv_freq is None else inv_freq)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., L, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     cos = cos[..., None, :]  # broadcast over heads
@@ -432,6 +435,21 @@ def paged_kv_write_rows(pool_leaf, page_table, rows, positions,
         rows.astype(pool_leaf.dtype))
 
 
+@jax.named_scope("kv_update")
+def paged_kv_write_block(pool_leaf, page_table, rows, positions,
+                         page_size: int):
+    """Write the new rows of every layer, for every position of every
+    row, into a stacked pool.  pool_leaf: (layers, num_pages, page_size,
+    C); rows: (layers, B, L, C); positions: (B, L)."""
+    n = rows.shape[0]
+    phys = jnp.take_along_axis(page_table, positions // page_size, axis=1)
+    shape = rows.shape[:3]
+    layer = jnp.broadcast_to(jnp.arange(n)[:, None, None], shape)
+    return pool_leaf.at[layer, jnp.broadcast_to(phys, shape),
+                        jnp.broadcast_to(positions % page_size, shape)].set(
+        rows.astype(pool_leaf.dtype))
+
+
 @jax.named_scope("kv_gather")
 def paged_kv_gather(pool_leaf, page_table):
     """Gather a sequence-contiguous (B, S_max, K*hd) view of each row's
@@ -488,11 +506,13 @@ def _on_tpu() -> bool:
 
 def paged_decode_kernel(cfg: ModelConfig, tp: int, L: int) -> bool:
     """Whether paged attention over L rows runs the Pallas paged-decode
-    kernel (`attention_paged_decode`): a single-token decode on a TPU
-    with the KV heads sharded over the model axis.  Prefill, the
+    kernel (`attention_paged_decode`, or `mla_paged_decode` in its latent
+    mode): a single-token decode on a TPU with the KV heads sharded over
+    the model axis, or any MLA decode.  Prefill, the
     replicated-KV layout and every other backend keep the gather path
     (`attention_paged`), the reference the kernel is tested against."""
-    return L == 1 and _on_tpu() and not _gqa_dims(cfg, tp)[2]
+    return L == 1 and _on_tpu() and (cfg.attn == "mla"
+                                     or not _gqa_dims(cfg, tp)[2])
 
 
 def _layer_window(cfg: ModelConfig, is_local_layer: bool):
@@ -602,6 +622,52 @@ def attention_paged_decode(comm: Comm, cfg: ModelConfig, p: Params, x,
 # MLA (deepseek-v3): latent KV, cache = compressed c_kv (+ rope key)
 # ---------------------------------------------------------------------------
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def mla_rope_freqs(cfg: ModelConfig):
+    """Inverse frequencies (qk_rope/2,) of the MLA rope lanes.  Under
+    YaRN each frequency j moves from theta's own to 1/factor of it along
+    r_j = clip((j - low) / (high - low), 0, 1), where low and high are the
+    lanes that turn beta_fast and beta_slow times over the original
+    context: inv_j (1 - r_j) + (inv_j / factor) r_j."""
+    dim = cfg.mla.qk_rope_dim
+    inv = 1.0 / (cfg.rope_theta ** (np.arange(0, dim, 2) / dim))
+    y = cfg.yarn
+    if y is not None:
+        def lane(turns):
+            return dim * math.log(y.original_max_pos / (turns * 2 * math.pi)) \
+                / (2 * math.log(cfg.rope_theta))
+        low = max(math.floor(lane(y.beta_fast)), 0)
+        high = min(math.ceil(lane(y.beta_slow)), dim - 1)
+        r = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                    0.0, 1.0)
+        inv = inv * (1.0 - r) + inv / y.factor * r
+    return jnp.asarray(inv, jnp.float32)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk_nope + qk_rope), times mscale(factor, mscale_all_dim)^2
+    under YaRN."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    if cfg.yarn is not None:
+        scale *= _yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig, x, positions):
+    """Rope on the MLA rope lanes; YaRN scales cos and sin by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    out = rope(x, positions, cfg.rope_theta, mla_rope_freqs(cfg))
+    y = cfg.yarn
+    if y is not None and y.mscale != y.mscale_all_dim:
+        out = out * (_yarn_mscale(y.factor, y.mscale)
+                     / _yarn_mscale(y.factor, y.mscale_all_dim))
+    return out.astype(x.dtype)
+
+
 def init_mla(key, cfg: ModelConfig, tp: int) -> Params:
     m = cfg.mla
     d = cfg.d_model
@@ -625,36 +691,152 @@ def init_mla(key, cfg: ModelConfig, tp: int) -> Params:
     }
 
 
-def mla_attention(comm: Comm, cfg: ModelConfig, p: Params, x, positions):
+def _mla_qkv(cfg: ModelConfig, p: Params, x, positions):
+    """q_nope (B, L, H, nope), q_rope (B, L, H, rope) with rope applied,
+    and the latent rows [c_kv | k_rope] (B, L, kv_lora + rope) that the
+    cache holds."""
     m = cfg.mla
-    tp = comm.axis_size(comm.axes.model)
-    nq_local = cfg.n_heads // tp
-    B, L, d = x.shape
-    cq = rms_norm(_dense(x, p["wq_a"]), p["q_norm"])
-    q = _dense(cq, p["wq_b"]).reshape(B, L, nq_local,
-                                      m.qk_nope_dim + m.qk_rope_dim)
-    q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    B, L, _ = x.shape
+    with jax.named_scope("attn_proj"):
+        cq = rms_norm(_dense(x, p["wq_a"]), p["q_norm"])
+        q = _dense(cq, p["wq_b"]).reshape(B, L, -1,
+                                          m.qk_nope_dim + m.qk_rope_dim)
+        q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
+        q_rope = _mla_rope(cfg, q_rope, positions)
+        kv_a = _dense(x, p["wkv_a"])
+        c_kv = rms_norm(kv_a[..., :m.kv_lora_rank], p["kv_norm"])
+        k_rope = _mla_rope(cfg, kv_a[..., None, m.kv_lora_rank:],
+                           positions)[..., 0, :]
+    return q_nope, q_rope, jnp.concatenate([c_kv, k_rope], -1)
 
-    kv_a = _dense(x, p["wkv_a"])
-    c_kv = rms_norm(kv_a[..., :m.kv_lora_rank], p["kv_norm"])
-    k_rope = rope(kv_a[..., None, m.kv_lora_rank:], positions, cfg.rope_theta)
 
-    kv = _dense(c_kv, p["wkv_b"]).reshape(B, L, nq_local,
-                                          m.qk_nope_dim + m.v_dim)
-    k_nope, v = kv[..., :m.qk_nope_dim], kv[..., m.qk_nope_dim:]
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope, (B, L, nq_local, m.qk_rope_dim))],
-        -1)
-    qf = jnp.concatenate([q_nope, q_rope], -1)
-    o = kops.attention(
-        qf.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=True,
-        sm_scale=1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim),
-        use_pallas=cfg.use_pallas, blockwise_unroll=cfg.probe_unroll)
-    o = o.transpose(0, 2, 1, 3).reshape(B, L, nq_local * m.v_dim)
-    return comm.allreduce(_dense(o.astype(cfg.dtype), p["wo"]),
-                          comm.axes.model)
+def _mla_full(comm: Comm, cfg: ModelConfig, p: Params, x, positions, *,
+              blockwise: bool = False):
+    """Non-absorbed causal MLA over the rows of x (keys are x's own rows):
+    per-head keys [W_kb c_kv | k_rope] and values W_vb c_kv.  Returns the
+    output (B, L, d) and the latent rows (B, L, kv_lora + rope)."""
+    m = cfg.mla
+    B, L, _ = x.shape
+    q_nope, q_rope, lat = _mla_qkv(cfg, p, x, positions)
+    with jax.named_scope("attn_proj"):
+        kv = _dense(lat[..., :m.kv_lora_rank], p["wkv_b"]).reshape(
+            B, L, -1, m.qk_nope_dim + m.v_dim)
+        k_nope, v = kv[..., :m.qk_nope_dim], kv[..., m.qk_nope_dim:]
+        nh = k_nope.shape[2]
+        k_rope = jnp.broadcast_to(lat[:, :, None, m.kv_lora_rank:],
+                                  (B, L, nh, m.qk_rope_dim))
+        k = jnp.concatenate([k_nope, k_rope], -1).transpose(0, 2, 1, 3)
+        q = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+    with jax.named_scope("attend"):
+        if blockwise:
+            o = kref.attention_blockwise(q, k, v, causal=True,
+                                         sm_scale=mla_softmax_scale(cfg),
+                                         block=min(L, 512))
+        else:
+            o = kops.attention(q, k, v, causal=True,
+                               sm_scale=mla_softmax_scale(cfg),
+                               use_pallas=cfg.use_pallas,
+                               blockwise_unroll=cfg.probe_unroll)
+    o = o.transpose(0, 2, 1, 3).reshape(B, L, nh * m.v_dim)
+    with jax.named_scope("attn_proj"):
+        y = _dense(o.astype(cfg.dtype), p["wo"])
+    return comm.allreduce(y, comm.axes.model), lat
+
+
+def mla_attention(comm: Comm, cfg: ModelConfig, p: Params, x, positions):
+    return _mla_full(comm, cfg, p, x, positions)[0]
+
+
+def latent_width(cfg: ModelConfig) -> int:
+    """Lanes of one row of the latent pool: [c_kv | k_rope], zero-padded
+    to a whole 128-lane tile (the TPU's HBM layout pads the row to it
+    anyway, and the decode kernel's page DMA must move whole tiles)."""
+    n = cfg.mla.kv_lora_rank + cfg.mla.qk_rope_dim
+    return -(-n // 128) * 128
+
+
+def _pad_lanes(x, width: int):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def mla_paged_prefill(comm: Comm, cfg: ModelConfig, p: Params, x,
+                      positions):
+    """MLA of the engine's paged prefill, whose prompt starts at position
+    0, so its keys are its own rows: the non-absorbed form, blockwise over
+    keys.  Returns the output (B, L, d) and the latent rows (B, L,
+    `latent_width`) for the caller to write into the latent pages."""
+    y, lat = _mla_full(comm, cfg, p, x, positions, blockwise=True)
+    return y, _pad_lanes(lat, latent_width(cfg))
+
+
+@jax.named_scope("attend")
+def latent_attend_gather(q, new_row, pool, layer, page_table, positions,
+                         v_lanes: int):
+    """Absorbed latent attention of one new row per slot against its pages,
+    gathered: the reference of the paged-decode kernel's latent mode.
+
+    q: (B, H, C) f32, scaled; new_row: (B, C) the slot's latent row;
+    pool: (layers, pages, page_size, C), read at `layer`; keys are the
+    pool's positions < pos plus the new row, values the first `v_lanes`
+    lanes of each key.  Returns (B, H, v_lanes) f32."""
+    lat = paged_kv_gather(pool[layer], page_table).astype(jnp.float32)
+    new = new_row.astype(pool.dtype).astype(jnp.float32)
+    s = jnp.einsum("bhc,bsc->bhs", q, lat)
+    valid = jnp.arange(lat.shape[1])[None, :] < positions[:, None]
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    s_new = jnp.einsum("bhc,bc->bh", q, new)[..., None]
+    mx = jnp.maximum(jnp.max(s, -1, keepdims=True), s_new)
+    pr, p_new = jnp.exp(s - mx), jnp.exp(s_new - mx)
+    ctx = jnp.einsum("bhs,bsv->bhv", pr, lat[..., :v_lanes]) \
+        + p_new * new[:, None, :v_lanes]
+    return ctx / (jnp.sum(pr, -1, keepdims=True) + p_new)
+
+
+def mla_paged_decode(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
+                     layer, page_table, positions, *, page_size: int,
+                     kernel: bool):
+    """Absorbed single-token MLA against the latent pages.
+
+    x: (B, 1, d); pool: the WHOLE stacked latent pool (layers, pages,
+    page_size, `latent_width`), read in place at `layer`.  The query
+    absorbs W_kb, q_abs = q_nope W_kb^T, so each score is [q_abs | q_rope]
+    . [c_kv | k_rope] over the cached latent rows; the context sum p c_kv
+    is lifted by W_vb.  Through the paged-decode kernel's latent mode
+    (`kernel`) or its gather reference.  The query and the row are
+    zero-padded to the pool's width, which adds exact zeros to every
+    score.  Returns the output (B, 1, d) and the slot's new latent row
+    (B, `latent_width`) for the caller to write once every layer has
+    run."""
+    m = cfg.mla
+    B = x.shape[0]
+    q_nope, q_rope, lat = _mla_qkv(cfg, p, x, positions)
+    wkv = p["wkv_b"].reshape(m.kv_lora_rank, -1, m.qk_nope_dim + m.v_dim)
+    wkv = wkv.astype(cfg.dtype)
+    with jax.named_scope("mla_absorb"):
+        q_abs = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0],
+                           wkv[..., :m.qk_nope_dim],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_abs, q_rope[:, 0].astype(jnp.float32)], -1) \
+            * mla_softmax_scale(cfg)
+        q = _pad_lanes(q, pool.shape[-1])
+    row = _pad_lanes(lat[:, 0], pool.shape[-1])
+    if kernel:
+        with jax.named_scope("attend"):
+            ctx = kops.paged_decode_attention(
+                q, row[:, None], None, pool, None, layer, page_table,
+                positions[:, 0], page_size=page_size,
+                v_lanes=m.kv_lora_rank)
+    else:
+        ctx = latent_attend_gather(q, row, pool, layer, page_table,
+                                   positions[:, 0], m.kv_lora_rank)
+    with jax.named_scope("mla_absorb"):
+        o = jnp.einsum("bhr,rhv->bhv", ctx.astype(cfg.dtype),
+                       wkv[..., m.qk_nope_dim:],
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("attn_proj"):
+        y = _dense(o.reshape(B, 1, -1).astype(cfg.dtype), p["wo"])
+    return comm.allreduce(y, comm.axes.model), row.astype(pool.dtype)
 
 
 def init_mla_cache(cfg: ModelConfig, batch_local: int, cache_len: int):
@@ -670,16 +852,9 @@ def mla_decode(comm: Comm, cfg: ModelConfig, p: Params, x, cache, position):
     tp = comm.axis_size(comm.axes.model)
     nq_local = cfg.n_heads // tp
     B = x.shape[0]
-    cq = rms_norm(_dense(x, p["wq_a"]), p["q_norm"])
-    q = _dense(cq, p["wq_b"]).reshape(B, 1, nq_local,
-                                      m.qk_nope_dim + m.qk_rope_dim)
-    q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = rope(q_rope, position[:, None], cfg.rope_theta)
-
-    kv_a = _dense(x, p["wkv_a"])
-    c_kv_new = rms_norm(kv_a[..., :m.kv_lora_rank], p["kv_norm"])
-    k_rope_new = rope(kv_a[..., None, m.kv_lora_rank:],
-                      position[:, None], cfg.rope_theta)[:, :, 0]
+    q_nope, q_rope, lat = _mla_qkv(cfg, p, x, position[:, None])
+    c_kv_new = lat[:, :, :m.kv_lora_rank]
+    k_rope_new = lat[:, :, m.kv_lora_rank:]
 
     upd = lambda c, u, i: lax.dynamic_update_slice_in_dim(c, u, i, axis=0)
     ckv = jax.vmap(upd)(cache["c_kv"], c_kv_new.astype(cfg.dtype), position)
@@ -693,7 +868,7 @@ def mla_decode(comm: Comm, cfg: ModelConfig, p: Params, x, cache, position):
     w_v = wkv[..., m.qk_nope_dim:]         # (r, h, v)
     q_abs = jnp.einsum("bohn,rhn->bohr", q_nope.astype(jnp.float32),
                        w_k.astype(jnp.float32))   # (B,1,h,r)
-    sc = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    sc = mla_softmax_scale(cfg)
     logits = (jnp.einsum("bohr,bsr->bhs", q_abs,
                          ckv.astype(jnp.float32)) +
               jnp.einsum("bohn,bsn->bhs", q_rope.astype(jnp.float32),
@@ -726,10 +901,14 @@ def init_mlp(key, cfg: ModelConfig, tp: int, d_ff: int | None = None) -> Params:
     }
 
 
-@jax.named_scope("mlp")
-def mlp(comm: Comm, cfg: ModelConfig, p: Params, x):
+def _swiglu(comm: Comm, p: Params, x):
     h = jax.nn.silu(_dense(x, p["w_gate"])) * _dense(x, p["w_up"])
     return comm.allreduce(_dense(h, p["w_down"]), comm.axes.model)
+
+
+@jax.named_scope("mlp")
+def mlp(comm: Comm, cfg: ModelConfig, p: Params, x):
+    return _swiglu(comm, p, x)
 
 
 # ---------------------------------------------------------------------------
@@ -744,6 +923,8 @@ def init_moe(key, cfg: ModelConfig, tp: int, dp: int = 1) -> Params:
     mo = cfg.moe
     d = cfg.d_model
     e_local = -(-mo.n_experts // moe_ep_size(cfg, tp, dp))
+    if mo.experts_held is not None:
+        e_local = mo.experts_held
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
 
     def nrm(k, shape, fan):
@@ -755,9 +936,86 @@ def init_moe(key, cfg: ModelConfig, tp: int, dp: int = 1) -> Params:
         "w_up": nrm(k3, (e_local, d, mo.d_ff), d),
         "w_down": nrm(k4, (e_local, mo.d_ff, d), mo.d_ff),
     }
+    if mo.correction_bias:
+        p["router_bias"] = jnp.zeros((mo.n_experts,), jnp.float32)
     if mo.n_shared:
         p["shared"] = init_mlp(k5, cfg, tp, d_ff=mo.n_shared * mo.d_ff)
     return p
+
+
+def route(cfg: ModelConfig, p: Params, x):
+    """Top-k routing of token rows x (T, d) over all n_experts.
+
+    Scores s = softmax or sigmoid of the router logits (computed in f32).
+    Selection ranks s + b (b the correction bias, where the config has
+    one); with n_group > 1 a group scores the sum of its top 2 of s + b
+    and only the topk_group best groups' experts stay eligible.  Gates
+    are the selected experts' s, normalised to sum 1 and scaled by
+    routed_scale.  Returns (s (T, E), experts (T, k), gates (T, k))."""
+    mo = cfg.moe
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if mo.score_func == "sigmoid"
+              else jax.nn.softmax(logits, -1))
+    sel = scores + p["router_bias"] if mo.correction_bias else scores
+    if mo.n_group > 1:
+        T, E = sel.shape
+        per = E // mo.n_group
+        group_score = lax.top_k(sel.reshape(T, mo.n_group, per), 2)[0].sum(-1)
+        _, groups = lax.top_k(group_score, mo.topk_group)
+        keep = jnp.zeros((T, mo.n_group), bool).at[
+            jnp.arange(T)[:, None], groups].set(True)
+        sel = jnp.where(jnp.repeat(keep, per, axis=1), sel, -jnp.inf)
+    _, experts = lax.top_k(sel, mo.top_k)
+    gates = jnp.take_along_axis(scores, experts, -1)
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9) \
+        * mo.routed_scale
+    return scores, experts, gates
+
+
+def moe_held(comm: Comm, cfg: ModelConfig, p: Params, x, valid=None):
+    """Dropless MoE over the expert share this chip holds (serving).
+
+    Every row routes over all n_experts (`route`); the rows of the held
+    experts [experts_offset, experts_offset + experts_held) are sorted by
+    expert and go through one grouped matmul each for gate, up and down
+    (no capacity, nothing dropped); assignments to the other experts add
+    nothing here.  The shared experts run on every row.  Rows where
+    `valid` (B, L) is False (a batch's empty slots) route nowhere.
+    Returns (out (B, L, d), rows (experts_held,) int32: the token rows
+    each held expert computed)."""
+    mo = cfg.moe
+    held = mo.experts_held or mo.n_experts
+    B, L, d = x.shape
+    flat = x.reshape(B * L, d)
+    with jax.named_scope("moe_route"):
+        _, experts, gates = route(cfg, p, flat)
+        local = experts - mo.experts_offset
+        mine = (local >= 0) & (local < held)
+        if valid is not None:
+            mine &= valid.reshape(-1, 1)
+        group = jnp.where(mine, local, held).reshape(-1)   # held: nowhere
+        order = jnp.argsort(group, stable=True)
+        rows = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+        tok = order // mo.top_k
+        w = jnp.where(mine, gates, 0.0).reshape(-1)[order]
+    with jax.named_scope("moe_experts"):
+        xs = flat[tok]
+        h = jax.nn.silu(lax.ragged_dot(xs, p["w_gate"].astype(x.dtype), rows)) \
+            * lax.ragged_dot(xs, p["w_up"].astype(x.dtype), rows)
+        y = lax.ragged_dot(h, p["w_down"].astype(x.dtype), rows)
+        # rows past the held groups are left undefined by the grouped
+        # matmul (garbage, not zeros, on a TPU): drop them outright
+        held_row = jnp.arange(y.shape[0]) < jnp.sum(rows)
+        y = jnp.where(held_row[:, None], y.astype(jnp.float32) * w[:, None],
+                      0.0)
+        out = jnp.zeros((B * L, d), jnp.float32).at[tok].add(y)
+    out = out.reshape(B, L, d).astype(x.dtype)
+    if mo.n_shared:
+        with jax.named_scope("moe_shared"):
+            out = out + _swiglu(comm, p["shared"], x)
+    return out, rows
 
 
 def moe(comm: Comm, cfg: ModelConfig, p: Params, x):
@@ -769,6 +1027,8 @@ def moe(comm: Comm, cfg: ModelConfig, p: Params, x):
     returned the same way.  With ep_over_data the EP group is the flattened
     (data, model) PE space — 256-way expert sharding for deepseek-v3."""
     mo = cfg.moe
+    if mo.experts_held is not None:
+        raise ValueError("a held expert share serves through moe_held")
     tp = comm.axis_size(comm.axes.model)
     ep_axes = ((comm.axes.data, comm.axes.model) if mo.ep_over_data
                else comm.axes.model)
@@ -792,10 +1052,7 @@ def moe(comm: Comm, cfg: ModelConfig, p: Params, x):
     xs = lax.dynamic_slice_in_dim(flat, my * t_local, t_local, axis=0)
 
     # 2. route (over the real expert count)
-    gates = jax.nn.softmax(
-        _dense(xs, p["router"]).astype(jnp.float32), -1)       # (T, E)
-    topv, tope = lax.top_k(gates, mo.top_k)                    # (T, K)
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    gates, tope, topv = route(cfg, p, xs)                      # (T, E/K/K)
 
     # 3. capacity + dispatch buffers (E_pad, C, d) via scatter
     cap = max(1, int(mo.capacity_factor * t_local * mo.top_k

@@ -478,9 +478,17 @@ def prefill(comm: Comm, cfg: ModelConfig, params: Params, tokens=None, *,
 # ---------------------------------------------------------------------------
 
 def paged_families() -> tuple[str, ...]:
-    """Families the paged-KV serving path supports (attention KV caches;
-    SSM/MLA state is not paged — the engine guards on this)."""
-    return ("dense", "vlm")
+    """Families the paged-KV serving path supports: attention KV caches,
+    and MoE with MLA, whose latent rows are paged (SSM state is not
+    paged — the engine guards on `paged_supported`)."""
+    return ("dense", "vlm", "moe")
+
+
+def paged_supported(cfg: ModelConfig) -> bool:
+    """Whether the paged serving path runs this model: a family of
+    `paged_families`, the MoE one with MLA attention."""
+    return cfg.family in paged_families() and (
+        cfg.family != "moe" or cfg.attn == "mla")
 
 
 def init_kv_pool(cfg: ModelConfig, tp: int, num_pages: int,
@@ -490,10 +498,17 @@ def init_kv_pool(cfg: ModelConfig, tp: int, num_pages: int,
     merged into the lane dim, (layers, num_pages, page_size, K*hd), so a
     page of one layer is one contiguous tile the decode kernel moves in
     one DMA.  Page p of every sequence lives at the SAME physical index
-    in every layer's pool, so one page table serves the whole stack."""
-    if cfg.family not in paged_families():
+    in every layer's pool, so one page table serves the whole stack.
+    MLA pages its latent rows: one pool {"latent": (layers, num_pages,
+    page_size, L.latent_width)} over every layer, dense and MoE, not
+    split into K and V."""
+    if not paged_supported(cfg):
         raise ValueError(
-            f"paged KV supports {paged_families()}, not {cfg.family!r}")
+            f"paged KV supports {paged_families()} (moe with MLA), not "
+            f"{cfg.family!r} with {cfg.attn!r} attention")
+    if cfg.attn == "mla":
+        return {"latent": jnp.zeros((cfg.n_layers, num_pages, page_size,
+                                     L.latent_width(cfg)), cfg.dtype)}
 
     def stack(n, fn):
         one = fn()
@@ -508,6 +523,56 @@ def init_kv_pool(cfg: ModelConfig, tp: int, num_pages: int,
         return {"pairs_local": stack(cfg.n_layers // 2, one_pool),
                 "pairs_global": stack(cfg.n_layers // 2, one_pool)}
     return {"layers": stack(cfg.n_layers, one_pool)}
+
+
+def _paged_stack_latent(comm, cfg, params, pool, page_table, x, positions,
+                        page_size):
+    """The MoE+MLA stack against the latent pool: the leading dense layers,
+    then the MoE layers, each a scan with the whole pool as a constant.
+    Prefill (L > 1) attends the prompt's own rows in the non-absorbed
+    form; decode (L = 1) takes the absorbed form through the kernel's
+    latent mode (`L.paged_decode_kernel`) or its gather reference.  Each
+    layer's latent rows leave the scans as outputs and are written into
+    the pool in one scatter after them.  MoE layers compute their held
+    expert share (`L.moe_held`); in decode, slots at position 0 (the
+    engine's empty ones) route nowhere.  Returns (x, pool, expert rows
+    (MoE layers, experts_held))."""
+    decode = x.shape[1] == 1
+    kernel = L.paged_decode_kernel(cfg, _tp(comm), x.shape[1])
+    lat = pool["latent"]
+    valid = positions > 0 if decode else None
+
+    def step(x, xs):
+        bp, layer = xs
+        h = L.rms_norm(x, bp["ln1"])
+        if decode:
+            a, row = L.mla_paged_decode(
+                comm, cfg, bp["attn"], h, lat, layer, page_table, positions,
+                page_size=page_size, kernel=kernel)
+        else:
+            a, row = L.mla_paged_prefill(comm, cfg, bp["attn"], h, positions)
+        x = x + a
+        h = L.rms_norm(x, bp["ln2"])
+        if "moe" in bp:
+            m, rows = L.moe_held(comm, cfg, bp["moe"], h, valid)
+            return x + m, (row, rows)
+        return x + L.mlp(comm, cfg, bp["mlp"], h), (row,)
+
+    nd = cfg.moe.first_dense_layers
+    rows = []
+    if nd:
+        x, (r,) = _scan(cfg, step, x, (params["dense_layers"],
+                                       jnp.arange(nd, dtype=jnp.int32)))
+        rows.append(r)
+    x, (r, expert_rows) = _scan(
+        cfg, step, x, (params["layers"],
+                       jnp.arange(nd, cfg.n_layers, dtype=jnp.int32)))
+    rows.append(r)
+    rows = jnp.concatenate(rows)
+    if decode:
+        rows = rows[:, :, None]
+    lat = L.paged_kv_write_block(lat, page_table, rows, positions, page_size)
+    return x, {"latent": lat}, expert_rows
 
 
 def _paged_stack(comm, cfg, params, pool, page_table, x, positions,
@@ -573,8 +638,12 @@ def prefill_paged(comm: Comm, cfg: ModelConfig, params: Params, pool: Params,
     reserved (or null) pages; decode overwrites each position before the
     causal mask can ever expose it."""
     x = _embed_scaled(comm, cfg, params, tokens)
-    x, pool = _paged_stack(comm, cfg, params, pool, page_table, x,
-                           positions, page_size)
+    if cfg.attn == "mla":
+        x, pool, _ = _paged_stack_latent(comm, cfg, params, pool, page_table,
+                                         x, positions, page_size)
+    else:
+        x, pool = _paged_stack(comm, cfg, params, pool, page_table, x,
+                               positions, page_size)
     x = L.rms_norm(x, params["final_norm"])
     return L.lm_logits(comm, cfg, params["embed"], x), pool
 
@@ -583,10 +652,19 @@ def decode_step_paged(comm: Comm, cfg: ModelConfig, params: Params,
                       pool: Params, page_table, tokens, positions, *,
                       page_size: int):
     """One paged decode step: tokens (B,1), positions (B,) -> (logits
-    (B,1,vocab_local), pool).  Identical to `decode_step` numerics on a
+    (B,1,vocab_local), pool), and for the MoE+MLA family a third output,
+    the token rows each held expert of each MoE layer computed (MoE
+    layers, experts_held).  Identical to `decode_step` numerics on a
     full-length cache; reads are page-table indexed."""
     x = _embed_scaled(comm, cfg, params, tokens)
-    x, pool = _paged_stack(comm, cfg, params, pool, page_table, x,
-                           positions[:, None], page_size)
+    extra = ()
+    if cfg.attn == "mla":
+        x, pool, expert_rows = _paged_stack_latent(
+            comm, cfg, params, pool, page_table, x, positions[:, None],
+            page_size)
+        extra = (expert_rows,)
+    else:
+        x, pool = _paged_stack(comm, cfg, params, pool, page_table, x,
+                               positions[:, None], page_size)
     x = L.rms_norm(x, params["final_norm"])
-    return L.lm_logits(comm, cfg, params["embed"], x), pool
+    return (L.lm_logits(comm, cfg, params["embed"], x), pool) + extra

@@ -80,7 +80,8 @@ def _base_spec(path: tuple[str, ...], leaf, cfg: ModelConfig,
         return P(ax.model, None)
     if name == "head":
         return P(None, ax.model)
-    if name in ("q_norm", "kv_norm", "ln", "ln1", "ln2", "final_norm"):
+    if name in ("q_norm", "kv_norm", "ln", "ln1", "ln2", "final_norm",
+                "router_bias"):
         return P(None)
     if nd == 1:
         return P(None)

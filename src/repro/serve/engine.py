@@ -155,8 +155,13 @@ def serve_programs(cfg, mesh, *, page_size: int, backend: str = "shmem",
     prefill(params, pool, table (1, max_pages), tokens (1, Lb), positions
     (1, Lb), last_idx (1,)) and decode(params, pool, table (B, max_pages),
     tokens (B, 1), positions (B,)) each return (greedy tokens, f32
-    logits, pool).  Decode donates the pool: the kernel path writes the
-    step's new rows into it in place."""
+    logits, pool); for the MoE+MLA family decode also returns the token
+    rows each held expert of each MoE layer computed.  Decode donates the
+    pool: the kernel path writes the step's new rows into it in place;
+    so does the MoE+MLA prefill, whose latent rows are written after its
+    layers in one scatter.  An MLA latent pool is replicated over the
+    model axis (every head reads the one latent row); K/V pools are split
+    over it by head."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -170,7 +175,7 @@ def serve_programs(cfg, mesh, *, page_size: int, backend: str = "shmem",
     _, tp, _ = build.mesh_dims(mesh)
     _, pspecs = build.abstract_params(cfg, mesh)
     poolspecs = jax.tree.map(
-        lambda _: P(None, None, None, "model"),
+        lambda _: P(None, None, None, None if cfg.attn == "mla" else "model"),
         jax.eval_shape(lambda: transformer.init_kv_pool(cfg, tp, 1,
                                                         page_size)))
 
@@ -186,20 +191,22 @@ def serve_programs(cfg, mesh, *, page_size: int, backend: str = "shmem",
 
     def decode_fn(params, pool, table, tokens, positions):
         comm = Comm(axes, backend, **comm_kw)
-        logits, pool = transformer.decode_step_paged(
+        logits, pool, *extra = transformer.decode_step_paged(
             comm, cfg, params, pool, table, tokens, positions,
             page_size=page_size)
         lg = logits[:, 0]
         tok = sstep.sample_greedy(comm, lg)
-        return tok, lg, pool
+        return (tok, lg, pool, *extra)
 
     lg_spec = P(None, "model")
+    latent = cfg.attn == "mla"
+    extra_specs = (P(),) if latent else ()
     pjit = jax.jit(build.shard_mapped(
         prefill_fn, mesh, (pspecs, poolspecs, P(), P(), P(), P()),
-        (P(), lg_spec, poolspecs)))
+        (P(), lg_spec, poolspecs)), donate_argnums=(1,) if latent else ())
     djit = jax.jit(build.shard_mapped(
         decode_fn, mesh, (pspecs, poolspecs, P(), P(), P()),
-        (P(), lg_spec, poolspecs)), donate_argnums=1)
+        (P(), lg_spec, poolspecs) + extra_specs), donate_argnums=1)
     return pjit, djit, poolspecs
 
 
@@ -229,10 +236,11 @@ class ServeEngine:
         from ..models import layers, transformer
 
         cfg = dc.replace(cfg, fsdp=False)
-        if cfg.family not in transformer.paged_families():
+        if not transformer.paged_supported(cfg):
             raise ValueError(
-                f"paged serving supports {transformer.paged_families()}, "
-                f"not {cfg.family!r}")
+                f"paged serving supports {transformer.paged_families()} "
+                f"(moe with MLA), not {cfg.family!r} with {cfg.attn!r} "
+                f"attention")
         dp, tp, pod = build.mesh_dims(mesh)
         if dp != 1 or pod:
             raise ValueError("ServeEngine batches in engine slots; use a "
@@ -294,6 +302,9 @@ class ServeEngine:
                 mesh, (), poolspecs))()
         self.decode_path = ("kernel" if layers.paged_decode_kernel(
             cfg, tp, 1) else "gather")
+        self.kv_kind = "latent" if cfg.attn == "mla" else "gqa"
+        # (MoE layers, held experts) token rows of the last decode step
+        self.last_expert_rows = None
 
     # -- observability helpers ------------------------------------------------
     @contextlib.contextmanager
@@ -326,7 +337,8 @@ class ServeEngine:
     def program_texts(self) -> dict[str, str]:
         """Compiled HLO text of the prefill and decode programs at this
         engine's shapes, whose metadata carries the model's named scopes
-        (kv_update, kv_gather, attend, attn_proj, mlp, lm_head, sample)."""
+        (kv_update, kv_gather, attend, attn_proj, mlp, lm_head, sample; and
+        mla_absorb, moe_route, moe_experts, moe_shared for MoE+MLA)."""
         jax, jnp = self._jax, self._jnp
         table = self.kv.table
         Lb = self.prompt_bucket
@@ -485,14 +497,20 @@ class ServeEngine:
                             jnp.asarray(poss))
                 with self._span("serve.decode", n_pes=len(active),
                                 path=self.decode_path,
-                                kv_pages=self._kv_pages(poss)):
-                    tok, lg, self.pool = self._djit(
+                                kv_pages=self._kv_pages(poss),
+                                kv_kind=self.kv_kind):
+                    tok, lg, self.pool, *extra = self._djit(
                         self.params, self.pool, *args)
-                    tok = np.asarray(tok)      # force sync: step complete
+                    # force sync: step complete (the expert rows, when
+                    # there are any, come back in the same transfer)
+                    tok, *extra = self._jax.device_get((tok, *extra))
+                    if extra:
+                        self.last_expert_rows = extra[0]
                 if metrics is not None:
                     metrics.on_decode_step(len(active),
                                            time.perf_counter() - t0,
-                                           self.decode_path)
+                                           self.decode_path,
+                                           self.last_expert_rows)
                 with self._span("serve.emit"):
                     lg = np.asarray(lg) if self.capture_logits else None
                     for i in active:

@@ -255,6 +255,14 @@ class ServeMetrics:
             "serve.e2e_s", "submit -> eviction end-to-end latency")
         self.recovery_s = r.histogram(
             "serve.recovery_s", "PE-failure drain + re-queue wall time")
+        # MoE expert share (counts of token rows, not seconds)
+        self.expert_rows = r.histogram(
+            "serve.expert_rows",
+            "mean token rows a held expert computed per decode step",
+            lo=0.01, hi=1e5)
+        self.expert_rows_max = r.gauge(
+            "serve.expert_rows_max",
+            "most token rows one held expert computed in a decode step")
 
     # -- lifecycle hooks (ServeEngine calls these) ---------------------------
     def on_submit(self) -> None:
@@ -271,13 +279,17 @@ class ServeMetrics:
         self.ttft_s.observe(time.perf_counter() - st.t_submit)
 
     def on_decode_step(self, n_active: int, wall_s: float,
-                       path: str = "gather") -> None:
+                       path: str = "gather", expert_rows=None) -> None:
         """`path`: how the step's paged attention read the KV pool,
-        "kernel" (the paged-decode kernel) or "gather"."""
+        "kernel" (the paged-decode kernel) or "gather"; `expert_rows`:
+        the (MoE layers, held experts) token rows of the step, if any."""
         self.decode_steps.inc()
         self.decode_steps_by_path[path].inc()
         self.tokens_generated.inc(n_active)
         self.per_token_s.observe(wall_s)
+        if expert_rows is not None and expert_rows.size:
+            self.expert_rows.observe(float(expert_rows.mean()))
+            self.expert_rows_max.set(float(expert_rows.max()))
 
     def on_evict(self, st) -> None:
         self.requests_completed.inc()

@@ -123,6 +123,21 @@ def test_paged_decode_kernel_cell_widths(spec):
              spec((SLOTS,), jnp.int32))
 
 
+def test_paged_decode_latent_kernel_cell_widths(spec):
+    """The latent mode at deepseek-v3-serve-chat2k's widths: 128 slots x
+    256 pages of 16, 7 layers, one latent "head" of 576 lanes padded to
+    640, 128 query heads, values the first 512 lanes."""
+    slots, pages = 128, 256
+    pool = spec((7, slots * pages + 1, PAGE, 640), jnp.bfloat16)
+    _compile(lambda q, kn, kp, layer, table, pos:
+             pd.paged_decode_attention(q, kn, None, kp, None, layer, table,
+                                       pos, page_size=PAGE, v_lanes=512),
+             spec((slots, 128, 640), jnp.float32),
+             spec((slots, 1, 640), jnp.bfloat16), pool,
+             spec((), jnp.int32), spec((slots, pages), jnp.int32),
+             spec((slots,), jnp.int32))
+
+
 def test_paged_decode_program_updates_pool_in_place(topo, monkeypatch):
     """The serving engine's decode program at the cell's size, on the
     kernel path: the pool is donated and aliased to the output, and no

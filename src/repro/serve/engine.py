@@ -14,8 +14,10 @@ Three pieces, separable on purpose:
     that doesn't fit simply waits at the queue head (no skipping, so no
     starvation), and no `HeapError` ever escapes the engine.
   * `ServeEngine` — the device half: a paged prefill fast-path (ONE
-    forward pass over the prompt bucket that fills the sequence's KV
-    pages) plus a fixed-shape batched decode step over all slots.
+    forward pass over the smallest bucket of a short ladder that holds
+    the prompt, filling the sequence's KV pages; every bucket compiled
+    at construction) plus a fixed-shape batched decode step over all
+    slots.
     Inactive slots ride along masked (their page-table rows point at the
     reserved null page), so the decode step never recompiles as
     sequences join and leave.  On a TPU the decode's attention reads
@@ -31,6 +33,7 @@ the engine prices and records every per-step collective (DESIGN.md §13).
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
 import dataclasses
@@ -42,6 +45,25 @@ import numpy as np
 from .kv import PagedKV, PagePool, pages_for
 from ..core.fault import PEFailure, fault_event
 from ..core.heap import SymmetricHeap
+
+
+# prefill bucket ladder: the powers of two from 128 and the 1.5x points
+# from 768, so a prompt pads at most 2x below 512 and 1.5x above it
+PREFILL_LADDER = (128, 256, 512, 768, 1024, 1536, 2048)
+
+
+def prefill_buckets(prompt_bucket: int, page_size: int) -> tuple[int, ...]:
+    """The prompt lengths the engine compiles a prefill for: the rungs of
+    `PREFILL_LADDER` below `prompt_bucket` that are whole pages, then
+    `prompt_bucket` itself, the longest prompt accepted."""
+    return tuple(b for b in PREFILL_LADDER
+                 if b < prompt_bucket and b % page_size == 0) + (
+        int(prompt_bucket),)
+
+
+def bucket_for(buckets, n: int) -> int:
+    """The smallest of the ascending `buckets` that holds `n` tokens."""
+    return buckets[bisect.bisect_left(buckets, n)]
 
 
 @dataclasses.dataclass
@@ -215,6 +237,12 @@ class ServeEngine:
     decode over `max_slots` sequences, greedy sampling through the
     vocab-sharded `sample_greedy`.
 
+    Each admission prefills at the smallest of `prefill_buckets` that
+    holds its prompt (`prefill_buckets(prompt_bucket, page_size)`);
+    every bucket's prefill runs once at construction, against the null
+    page, so serving never compiles.  `prompt_bucket` is the longest
+    prompt accepted.
+
     The mesh provides tensor parallelism only (data axis must be 1: the
     batch lives in engine slots, not on a mesh axis).  `kv_heap_bytes`
     caps the per-PE symmetric-heap KV region — by default sized to hold
@@ -251,6 +279,7 @@ class ServeEngine:
         self.page_size = int(page_size)
         self.max_seq = int(max_seq)
         self.prompt_bucket = int(prompt_bucket)
+        self.prefill_buckets = prefill_buckets(prompt_bucket, page_size)
         self.max_slots = int(max_slots)
         self.eos_id = eos_id
         self.capture_logits = capture_logits
@@ -300,6 +329,12 @@ class ServeEngine:
                 lambda: transformer.init_kv_pool(cfg, tp, n_dev_pages,
                                                  page_size),
                 mesh, (), poolspecs))()
+            # compile every bucket now: a free slot's table row is all
+            # null page, which no valid read ever sees
+            null_row = self.kv.table[:1].copy()
+            for Lb in self.prefill_buckets:
+                self._prefill(null_row, np.zeros(1, np.int32), Lb)
+        jax.block_until_ready(self.pool)
         self.decode_path = ("kernel" if layers.paged_decode_kernel(
             cfg, tp, 1) else "gather")
         self.kv_kind = "latent" if cfg.attn == "mla" else "gqa"
@@ -323,6 +358,21 @@ class ServeEngine:
                     yield
             else:
                 yield
+
+    def _prefill(self, trow, prompt, Lb: int):
+        """One prefill of `prompt` at bucket `Lb` into the pages of table
+        row `trow` (1, max_pages).  Returns (first token, logits), both
+        on the device."""
+        jnp = self._jnp
+        toks = np.zeros((1, Lb), np.int32)
+        toks[0, :len(prompt)] = prompt
+        positions = jnp.broadcast_to(
+            jnp.arange(Lb, dtype=jnp.int32)[None], (1, Lb))
+        last = jnp.asarray([len(prompt) - 1], jnp.int32)
+        tok, lg, self.pool = self._pjit(
+            self.params, self.pool, jnp.asarray(trow), jnp.asarray(toks),
+            positions, last)
+        return tok, lg
 
     def _kv_pages(self, positions) -> int:
         """Pages one attention layer of this decode reads: on the kernel
@@ -463,23 +513,17 @@ class ServeEngine:
                 if metrics is not None:
                     metrics.on_admit(st)
                 self._req_event("admit", st.rid, slot=slot)
-                with self._span("serve.prefill"):
-                    Lb = self.prompt_bucket
-                    toks = np.zeros((1, Lb), np.int32)
-                    toks[0, :len(st.prompt)] = st.prompt
-                    positions = jnp.broadcast_to(
-                        jnp.arange(Lb, dtype=jnp.int32)[None], (1, Lb))
-                    trow = jnp.asarray(self.kv.table[slot:slot + 1])
-                    last = jnp.asarray([len(st.prompt) - 1], jnp.int32)
-                    tok, lg, self.pool = self._pjit(
-                        self.params, self.pool, trow, jnp.asarray(toks),
-                        positions, last)
+                Lb = bucket_for(self.prefill_buckets, len(st.prompt))
+                with self._span("serve.prefill", bucket=Lb,
+                                prompt_len=len(st.prompt)):
+                    tok, lg = self._prefill(self.kv.table[slot:slot + 1],
+                                            st.prompt, Lb)
                     tok = np.asarray(tok)      # force sync: first token
                 self._emit(st, tok[0],
                            np.asarray(lg)[0] if self.capture_logits
                            else None)
                 if metrics is not None:
-                    metrics.on_first_token(st)
+                    metrics.on_first_token(st, Lb)
                 self._req_event("first_token", st.rid)
                 admitted.append(st.rid)
 

@@ -213,6 +213,11 @@ class ServeMetrics:
             "serve.tokens_generated", "total generated tokens")
         self.prefill_runs = r.counter(
             "serve.prefill_runs", "paged prefill forward passes")
+        self.prefill_positions = r.counter(
+            "serve.prefill_positions",
+            "positions the prefills ran: each prompt's bucket")
+        self.prompt_positions = r.counter(
+            "serve.prompt_positions", "prompt tokens the prefills ran")
         self.decode_steps = r.counter(
             "serve.decode_steps", "batched decode steps executed")
         self.decode_steps_by_path = {
@@ -273,8 +278,14 @@ class ServeMetrics:
         self.requests_admitted.inc()
         self.admission_wait_s.observe(st.t_admit - st.t_submit)
 
-    def on_first_token(self, st) -> None:
+    def on_first_token(self, st, bucket: int | None = None) -> None:
+        """`bucket`: the prompt length the prefill ran at (the prompt's
+        own when not given); the padded share of the prefills is
+        1 - prompt_positions / prefill_positions."""
         self.prefill_runs.inc()
+        self.prompt_positions.inc(len(st.prompt))
+        self.prefill_positions.inc(len(st.prompt) if bucket is None
+                                   else bucket)
         self.tokens_generated.inc()
         self.ttft_s.observe(time.perf_counter() - st.t_submit)
 

@@ -402,47 +402,14 @@ def _cache_attend(cfg, q, ck, cv, valid, slot_map=None, comm=None,
 # ---------------------------------------------------------------------------
 
 @jax.named_scope("kv_update")
-def paged_kv_update(pool_leaf, page_table, new, positions, page_size: int):
-    """Scatter per-position rows into a paged KV pool.
-
-    pool_leaf: (num_pages, page_size, K*hd) — one layer's page pool;
-    page_table: (B, max_pages) int32 physical page ids (0 = null page);
-    new: (B, L, K, hd) rows to write; positions: (B, L) global positions.
-    Rows land at pool[page_table[b, pos // page_size], pos % page_size].
-    Distinct sequences own distinct pages, so batched writes never
-    collide except on the reserved null page (whose contents are never
-    read through a valid mask)."""
-    page = positions // page_size
-    off = positions % page_size
-    phys = jnp.take_along_axis(page_table, page, axis=1)     # (B, L)
-    rows = new.reshape(new.shape[:2] + pool_leaf.shape[-1:])
-    return pool_leaf.at[phys, off].set(rows.astype(pool_leaf.dtype))
-
-
-@jax.named_scope("kv_update")
-def paged_kv_write_rows(pool_leaf, page_table, rows, positions,
-                        page_size: int):
-    """Write one decode step's new rows of every layer into a stacked
-    pool.  pool_leaf: (layers, num_pages, page_size, K*hd); rows:
-    (layers, B, K*hd); positions: (B,)."""
-    n, B = rows.shape[:2]
-    phys = page_table[jnp.arange(B), positions // page_size]
-    # every index explicit, (layers, B) each, so the scatter's window is
-    # one K*hd row and XLA updates the pool in its own layout
-    layer = jnp.broadcast_to(jnp.arange(n)[:, None], (n, B))
-    return pool_leaf.at[layer, jnp.broadcast_to(phys, (n, B)),
-                        jnp.broadcast_to(positions % page_size, (n, B))].set(
-        rows.astype(pool_leaf.dtype))
-
-
-@jax.named_scope("kv_update")
 def paged_kv_write_block(pool_leaf, page_table, rows, positions,
                          page_size: int):
     """Write the new rows of every layer, for every position of every
     row, into a stacked pool.  pool_leaf: (layers, num_pages, page_size,
     C); rows: (layers, B, L, C); positions: (B, L)."""
     n = rows.shape[0]
-    phys = jnp.take_along_axis(page_table, positions // page_size, axis=1)
+    phys = page_table[jnp.arange(positions.shape[0])[:, None],
+                      positions // page_size]
     shape = rows.shape[:3]
     layer = jnp.broadcast_to(jnp.arange(n)[:, None, None], shape)
     return pool_leaf.at[layer, jnp.broadcast_to(phys, shape),
@@ -465,23 +432,22 @@ def paged_kv_gather(pool_leaf, page_table):
 def _attend_mq(cfg, q, ck, cv, valid, slot_map=None):
     """Multi-query generalization of `_cache_attend` for the paged path.
 
-    q: (B,L,Hq,hd); ck/cv: (B,S,K,hd); valid: (B,L,S) -> (B,L,Hq,hd).
-    Shared by paged prefill (L = prompt bucket) and paged decode (L = 1)
-    so both attend through identical einsum contractions — the engine's
-    batched-vs-alone bit-identity rests on every op being per-row."""
+    q: (B,L,Hq,hd) f32, already scaled; ck/cv: (B,S,K,hd); valid: (B,L,S)
+    -> (B,L,Hq,hd).  Shared by paged prefill (L = prompt bucket) and the
+    paged decode's gather reference (L = 1); every op is per row, which
+    the engine's batched-vs-alone bit-identity rests on."""
     B, S = ck.shape[0], ck.shape[1]
-    hd = cfg.hd
-    qf = q.astype(jnp.float32) / math.sqrt(hd)               # (B,L,Hq,hd)
+    hd = q.shape[-1]
     kf, vf = ck.astype(jnp.float32), cv.astype(jnp.float32)
     if slot_map is not None:
-        logits = jnp.einsum("blqd,bskd->blqks", qf, kf)
+        logits = jnp.einsum("blqd,bskd->blqks", q, kf)
         logits = jnp.einsum("blqks,qk->blqs", logits, slot_map)
     else:
         K = ck.shape[2]
-        group = qf.shape[2] // K
-        qg = qf.reshape(B, qf.shape[1], K, group, hd)
+        group = q.shape[2] // K
+        qg = q.reshape(B, q.shape[1], K, group, hd)
         logits = jnp.einsum("blkgd,bskd->blkgs", qg, kf) \
-            .reshape(B, qf.shape[1], K * group, S)
+            .reshape(B, q.shape[1], K * group, S)
     if cfg.softcap is not None:
         logits = cfg.softcap * jnp.tanh(logits / cfg.softcap)
     logits = jnp.where(valid[:, :, None, :], logits, -1e30)
@@ -504,15 +470,14 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def paged_decode_kernel(cfg: ModelConfig, tp: int, L: int) -> bool:
-    """Whether paged attention over L rows runs the Pallas paged-decode
+def paged_decode_kernel(cfg: ModelConfig, tp: int) -> bool:
+    """Whether the paged decode attention runs the Pallas paged-decode
     kernel (`attention_paged_decode`, or `mla_paged_decode` in its latent
-    mode): a single-token decode on a TPU with the KV heads sharded over
-    the model axis, or any MLA decode.  Prefill, the
-    replicated-KV layout and every other backend keep the gather path
-    (`attention_paged`), the reference the kernel is tested against."""
-    return L == 1 and _on_tpu() and (cfg.attn == "mla"
-                                     or not _gqa_dims(cfg, tp)[2])
+    mode): on a TPU, with the KV heads sharded over the model axis or with
+    MLA.  The replicated-KV layout and every other backend take the gather
+    reference the kernel is tested against (`kv_attend_gather`,
+    `latent_attend_gather`)."""
+    return _on_tpu() and (cfg.attn == "mla" or not _gqa_dims(cfg, tp)[2])
 
 
 def _layer_window(cfg: ModelConfig, is_local_layer: bool):
@@ -522,8 +487,9 @@ def _layer_window(cfg: ModelConfig, is_local_layer: bool):
 
 
 def _paged_qkv(comm: Comm, cfg: ModelConfig, p: Params, x, positions):
-    """q (B,L,Hq_local,hd), k/v (B,L,K_store,hd) and, for the replicated
-    KV layout, the one-hot q-head -> stored-KV-head map (else None)."""
+    """q (B,L,Hq_local,hd) f32, scaled by 1/sqrt(hd); k/v (B,L,K_store,hd)
+    rounded to the pool's dtype (`cfg.dtype`); and, for the replicated KV
+    layout, the one-hot q-head -> stored-KV-head map (else None)."""
     tp = comm.axis_size(comm.axes.model)
     B, L, d = x.shape
     hd = cfg.hd
@@ -544,11 +510,14 @@ def _paged_qkv(comm: Comm, cfg: ModelConfig, p: Params, x, positions):
         v = jnp.take(v, sidx, axis=2)
         q2 = jnp.asarray(q2slot)[rank]                       # (nq_local,)
         slot_map = jax.nn.one_hot(q2, ndk, dtype=jnp.float32)
-    return q, k, v, slot_map
+    return (q.astype(jnp.float32) / math.sqrt(hd), k.astype(cfg.dtype),
+            v.astype(cfg.dtype), slot_map)
 
 
-def _paged_out(comm: Comm, cfg: ModelConfig, p: Params, out):
-    """Ghost-head mask, output projection and the model-axis allreduce."""
+def _paged_out(comm: Comm, cfg: ModelConfig, p: Params, out, k, v):
+    """Ghost-head mask, output projection and the model-axis allreduce;
+    with the new K/V rows {"k","v"} (B, L, K_store*hd) for the caller to
+    write into the pool once every layer has run."""
     tp = comm.axis_size(comm.axes.model)
     B, L, nq_local, hd = out.shape
     if cfg.n_heads % tp:   # zero ghost heads
@@ -557,65 +526,81 @@ def _paged_out(comm: Comm, cfg: ModelConfig, p: Params, out):
     out = out.reshape(B, L, nq_local * hd).astype(cfg.dtype)
     with jax.named_scope("attn_proj"):
         y = _dense(out, p["wo"])
-    return comm.allreduce(y, comm.axes.model)
+    return comm.allreduce(y, comm.axes.model), {
+        "k": k.reshape(B, L, -1), "v": v.reshape(B, L, -1)}
 
 
-def attention_paged(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
-                    page_table, positions, *, page_size: int,
-                    is_local_layer: bool = False):
-    """GQA attention against a paged KV pool — one code path for prefill
-    (x: (B, L, d), L = prompt bucket) and decode (L = 1) off the kernel.
-
-    pool: {"k","v"} (num_pages, page_size, K_local*hd); page_table:
-    (B, max_pages) physical page ids.  K/V rows for every position are
-    scattered into the owning page, then each row's pages are gathered
-    back sequence-contiguous and attended with a causal(+window) mask.
-    Sliding windows are handled purely by masking (pages keep the full
-    sequence), so paged results equal the full-length dense cache path."""
-    hd = cfg.hd
+def attention_paged_prefill(comm: Comm, cfg: ModelConfig, p: Params, x,
+                            positions, *, keys: int,
+                            is_local_layer: bool = False):
+    """GQA attention of the engine's paged prefill, whose prompt starts at
+    position 0, so its keys are its own rows, under the causal(+window)
+    mask.  They are zero-padded to `keys` positions (the page table's
+    span): every bucket then attends one key length, and the f32 sums
+    over keys, whose rounding follows their length, give a prompt row
+    the same result in every bucket.  Returns the output (B, L, d) and
+    the K/V rows (`_paged_out`)."""
     q, k, v, slot_map = _paged_qkv(comm, cfg, p, x, positions)
-    B, K = k.shape[0], k.shape[2]
-    pk = paged_kv_update(pool["k"], page_table, k, positions, page_size)
-    pv = paged_kv_update(pool["v"], page_table, v, positions, page_size)
-    ck = paged_kv_gather(pk, page_table).reshape(B, -1, K, hd)
-    cv = paged_kv_gather(pv, page_table).reshape(B, -1, K, hd)
-
-    S_max = ck.shape[1]
     window = _layer_window(cfg, is_local_layer)
-    kv_pos = jnp.arange(S_max)[None, None, :]                # (1,1,S)
+    kv_pos = jnp.arange(keys)[None, None, :]
     valid = kv_pos <= positions[:, :, None]
     if window is not None:
-        valid &= kv_pos > (positions[:, :, None] - window)
+        valid &= kv_pos > positions[:, :, None] - window
+    pad = ((0, 0), (0, keys - x.shape[1]), (0, 0), (0, 0))
+    out = _attend_mq(cfg, q, jnp.pad(k, pad), jnp.pad(v, pad), valid,
+                     slot_map)
+    return _paged_out(comm, cfg, p, out, k, v)
 
-    out = _attend_mq(cfg, q, ck, cv, valid, slot_map)
-    return _paged_out(comm, cfg, p, out), {"k": pk, "v": pv}
+
+def kv_attend_gather(cfg: ModelConfig, q, k_new, v_new, pool, layer,
+                     page_table, positions, *, window=None, slot_map=None):
+    """Decode attention of one new row per slot against its pages,
+    gathered: the reference of the paged-decode kernel, on its operands.
+
+    q: (B, Hq, hd) f32, scaled; k_new, v_new: (B, K, hd) the slot's new
+    row; pool: {"k","v"} (layers, pages, page_size, K*hd), read at
+    `layer`; positions: (B,).  Keys are the pool's positions [pos -
+    window + 1, pos) plus the new row; `slot_map` as `_attend_mq`.
+    Returns (B, Hq, hd) f32."""
+    B, K, hd = k_new.shape
+    ck, cv = (jnp.concatenate(
+        [paged_kv_gather(pool[c][layer], page_table),
+         new.reshape(B, 1, -1).astype(pool[c].dtype)], 1).reshape(
+             B, -1, K, hd) for c, new in (("k", k_new), ("v", v_new)))
+    kv_pos = jnp.arange(ck.shape[1] - 1)[None, :]
+    valid = kv_pos < positions[:, None]
+    if window is not None:
+        valid &= kv_pos > positions[:, None] - window
+    valid = jnp.pad(valid, ((0, 0), (0, 1)), constant_values=True)
+    return _attend_mq(cfg, q[:, None], ck, cv, valid[:, None], slot_map)[:, 0]
 
 
 def attention_paged_decode(comm: Comm, cfg: ModelConfig, p: Params, x,
                            pool, layer, page_table, positions, *,
                            page_size: int, is_local_layer: bool = False):
-    """Single-token paged decode through the Pallas paged-decode kernel
-    (taken where `paged_decode_kernel` says).
+    """Single-token GQA decode against the paged pool, through the Pallas
+    paged-decode kernel where `paged_decode_kernel` says, else its gather
+    reference `kv_attend_gather`.
 
     x: (B, 1, d); pool: {"k","v"} the WHOLE stacked pool (layers,
     num_pages, page_size, K_local*hd), read in place at `layer`: a
     per-layer slice handed to a custom call would be materialised.  Each
-    slot reads only the pages holding its earlier positions; its new K/V
-    row is attended from registers and returned, (B, K_local*hd) each,
-    for the caller to write into the pool once every layer has run.
-    Same arithmetic as the gather path: bf16 K/V, f32 scores, softmax and
-    weighted sum."""
-    B = x.shape[0]
-    q, k, v, _ = _paged_qkv(comm, cfg, p, x, positions)
-    qf = q[:, 0].astype(jnp.float32) / math.sqrt(cfg.hd)
-    with jax.named_scope("attend"):
-        out = kops.paged_decode_attention(
-            qf, k[:, 0], v[:, 0], pool["k"], pool["v"], layer, page_table,
-            positions[:, 0], page_size=page_size,
-            window=_layer_window(cfg, is_local_layer), softcap=cfg.softcap)
-    rows = {c: a[:, 0].reshape(B, -1).astype(pool[c].dtype)
-            for c, a in (("k", k), ("v", v))}
-    return _paged_out(comm, cfg, p, out[:, None]), rows
+    slot reads the pages holding its earlier positions; its new K/V row
+    is attended from registers and returned (`_paged_out`).  Both paths:
+    bf16 K/V, f32 scores, softmax and weighted sum."""
+    q, k, v, slot_map = _paged_qkv(comm, cfg, p, x, positions)
+    window = _layer_window(cfg, is_local_layer)
+    if paged_decode_kernel(cfg, comm.axis_size(comm.axes.model)):
+        with jax.named_scope("attend"):
+            out = kops.paged_decode_attention(
+                q[:, 0], k[:, 0], v[:, 0], pool["k"], pool["v"], layer,
+                page_table, positions[:, 0], page_size=page_size,
+                window=window, softcap=cfg.softcap)
+    else:
+        out = kv_attend_gather(cfg, q[:, 0], k[:, 0], v[:, 0], pool, layer,
+                               page_table, positions[:, 0], window=window,
+                               slot_map=slot_map)
+    return _paged_out(comm, cfg, p, out[:, None], k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -764,10 +749,11 @@ def mla_paged_prefill(comm: Comm, cfg: ModelConfig, p: Params, x,
                       positions):
     """MLA of the engine's paged prefill, whose prompt starts at position
     0, so its keys are its own rows: the non-absorbed form, blockwise over
-    keys.  Returns the output (B, L, d) and the latent rows (B, L,
-    `latent_width`) for the caller to write into the latent pages."""
+    keys.  Returns the output (B, L, d) and the latent rows {"latent":
+    (B, L, `latent_width`)} for the caller to write into the latent
+    pages."""
     y, lat = _mla_full(comm, cfg, p, x, positions, blockwise=True)
-    return y, _pad_lanes(lat, latent_width(cfg))
+    return y, {"latent": _pad_lanes(lat, latent_width(cfg))}
 
 
 @jax.named_scope("attend")
@@ -794,22 +780,22 @@ def latent_attend_gather(q, new_row, pool, layer, page_table, positions,
 
 
 def mla_paged_decode(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
-                     layer, page_table, positions, *, page_size: int,
-                     kernel: bool):
+                     layer, page_table, positions, *, page_size: int):
     """Absorbed single-token MLA against the latent pages.
 
-    x: (B, 1, d); pool: the WHOLE stacked latent pool (layers, pages,
-    page_size, `latent_width`), read in place at `layer`.  The query
+    x: (B, 1, d); pool: {"latent": the WHOLE stacked latent pool (layers,
+    pages, page_size, `latent_width`)}, read in place at `layer`.  The query
     absorbs W_kb, q_abs = q_nope W_kb^T, so each score is [q_abs | q_rope]
     . [c_kv | k_rope] over the cached latent rows; the context sum p c_kv
     is lifted by W_vb.  Through the paged-decode kernel's latent mode
-    (`kernel`) or its gather reference.  The query and the row are
-    zero-padded to the pool's width, which adds exact zeros to every
-    score.  Returns the output (B, 1, d) and the slot's new latent row
-    (B, `latent_width`) for the caller to write once every layer has
-    run."""
+    where `paged_decode_kernel` says, else its gather reference.  The
+    query and the row are zero-padded to the pool's width, which adds
+    exact zeros to every score.  Returns the output (B, 1, d) and the
+    slot's new latent row {"latent": (B, 1, `latent_width`)} for the
+    caller to write once every layer has run."""
     m = cfg.mla
     B = x.shape[0]
+    pool = pool["latent"]
     q_nope, q_rope, lat = _mla_qkv(cfg, p, x, positions)
     wkv = p["wkv_b"].reshape(m.kv_lora_rank, -1, m.qk_nope_dim + m.v_dim)
     wkv = wkv.astype(cfg.dtype)
@@ -821,7 +807,7 @@ def mla_paged_decode(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
             * mla_softmax_scale(cfg)
         q = _pad_lanes(q, pool.shape[-1])
     row = _pad_lanes(lat[:, 0], pool.shape[-1])
-    if kernel:
+    if paged_decode_kernel(cfg, comm.axis_size(comm.axes.model)):
         with jax.named_scope("attend"):
             ctx = kops.paged_decode_attention(
                 q, row[:, None], None, pool, None, layer, page_table,
@@ -836,7 +822,8 @@ def mla_paged_decode(comm: Comm, cfg: ModelConfig, p: Params, x, pool,
                        preferred_element_type=jnp.float32)
     with jax.named_scope("attn_proj"):
         y = _dense(o.reshape(B, 1, -1).astype(cfg.dtype), p["wo"])
-    return comm.allreduce(y, comm.axes.model), row.astype(pool.dtype)
+    return comm.allreduce(y, comm.axes.model), {
+        "latent": row[:, None].astype(pool.dtype)}
 
 
 def init_mla_cache(cfg: ModelConfig, batch_local: int, cache_len: int):

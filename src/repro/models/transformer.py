@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from ..parallel.comm import Comm
 from . import layers as L
@@ -40,10 +41,6 @@ def _maybe_remat(cfg: ModelConfig, fn):
         return jax.checkpoint(
             fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     return fn
-
-
-def _tp(comm: Comm) -> int:
-    return comm.axis_size(comm.axes.model)
 
 
 def _scan(cfg, body, carry, xs):
@@ -493,139 +490,102 @@ def paged_supported(cfg: ModelConfig) -> bool:
 
 def init_kv_pool(cfg: ModelConfig, tp: int, num_pages: int,
                  page_size: int) -> Params:
-    """Stacked per-layer-group paged KV pools: like `init_cache` but the
-    (B, S) cache dims become (num_pages, page_size) and the K heads are
-    merged into the lane dim, (layers, num_pages, page_size, K*hd), so a
-    page of one layer is one contiguous tile the decode kernel moves in
-    one DMA.  Page p of every sequence lives at the SAME physical index
-    in every layer's pool, so one page table serves the whole stack.
-    MLA pages its latent rows: one pool {"latent": (layers, num_pages,
-    page_size, L.latent_width)} over every layer, dense and MoE, not
-    split into K and V."""
+    """The paged KV pool: one stack per leaf over all `n_layers`, indexed
+    by the global layer index (local/global pairs: local layer i of the
+    scan is layer 2i, the global one 2i+1; MoE: the dense layers first).
+    GQA keeps {"k", "v"}, each (layers, num_pages, page_size, K*hd) with
+    the K heads merged into the lane dim, so a page of one layer is one
+    contiguous tile the decode kernel moves in one DMA.  MLA pages its
+    latent rows: {"latent": (layers, num_pages, page_size,
+    L.latent_width)}, not split into K and V.  Page p of every sequence
+    lives at the SAME physical index in every layer, so one page table
+    serves the whole stack."""
     if not paged_supported(cfg):
         raise ValueError(
             f"paged KV supports {paged_families()} (moe with MLA), not "
             f"{cfg.family!r} with {cfg.attn!r} attention")
+    lanes = ({"latent": L.latent_width(cfg)} if cfg.attn == "mla" else
+             {c: a.shape[2] * a.shape[3]
+              for c, a in L.init_attn_cache(cfg, tp, 1, 1).items()})
+    return {c: jnp.zeros((cfg.n_layers, num_pages, page_size, w), cfg.dtype)
+            for c, w in lanes.items()}
+
+
+def kv_pool_specs(cfg: ModelConfig):
+    """The pool's partition over the model axis: every head reads the one
+    MLA latent row, so it is replicated; K/V pools are split by head."""
     if cfg.attn == "mla":
-        return {"latent": jnp.zeros((cfg.n_layers, num_pages, page_size,
-                                     L.latent_width(cfg)), cfg.dtype)}
-
-    def stack(n, fn):
-        one = fn()
-        return jax.tree.map(lambda a: jnp.broadcast_to(
-            a[None], (n,) + a.shape).copy(), one)
-
-    def one_pool():
-        return {c: a.reshape(num_pages, page_size, -1) for c, a in
-                L.init_attn_cache(cfg, tp, num_pages, page_size).items()}
-
-    if cfg.local_global_period:
-        return {"pairs_local": stack(cfg.n_layers // 2, one_pool),
-                "pairs_global": stack(cfg.n_layers // 2, one_pool)}
-    return {"layers": stack(cfg.n_layers, one_pool)}
-
-
-def _paged_stack_latent(comm, cfg, params, pool, page_table, x, positions,
-                        page_size):
-    """The MoE+MLA stack against the latent pool: the leading dense layers,
-    then the MoE layers, each a scan with the whole pool as a constant.
-    Prefill (L > 1) attends the prompt's own rows in the non-absorbed
-    form; decode (L = 1) takes the absorbed form through the kernel's
-    latent mode (`L.paged_decode_kernel`) or its gather reference.  Each
-    layer's latent rows leave the scans as outputs and are written into
-    the pool in one scatter after them.  MoE layers compute their held
-    expert share (`L.moe_held`); in decode, slots at position 0 (the
-    engine's empty ones) route nowhere.  Returns (x, pool, expert rows
-    (MoE layers, experts_held))."""
-    decode = x.shape[1] == 1
-    kernel = L.paged_decode_kernel(cfg, _tp(comm), x.shape[1])
-    lat = pool["latent"]
-    valid = positions > 0 if decode else None
-
-    def step(x, xs):
-        bp, layer = xs
-        h = L.rms_norm(x, bp["ln1"])
-        if decode:
-            a, row = L.mla_paged_decode(
-                comm, cfg, bp["attn"], h, lat, layer, page_table, positions,
-                page_size=page_size, kernel=kernel)
-        else:
-            a, row = L.mla_paged_prefill(comm, cfg, bp["attn"], h, positions)
-        x = x + a
-        h = L.rms_norm(x, bp["ln2"])
-        if "moe" in bp:
-            m, rows = L.moe_held(comm, cfg, bp["moe"], h, valid)
-            return x + m, (row, rows)
-        return x + L.mlp(comm, cfg, bp["mlp"], h), (row,)
-
-    nd = cfg.moe.first_dense_layers
-    rows = []
-    if nd:
-        x, (r,) = _scan(cfg, step, x, (params["dense_layers"],
-                                       jnp.arange(nd, dtype=jnp.int32)))
-        rows.append(r)
-    x, (r, expert_rows) = _scan(
-        cfg, step, x, (params["layers"],
-                       jnp.arange(nd, cfg.n_layers, dtype=jnp.int32)))
-    rows.append(r)
-    rows = jnp.concatenate(rows)
-    if decode:
-        rows = rows[:, :, None]
-    lat = L.paged_kv_write_block(lat, page_table, rows, positions, page_size)
-    return x, {"latent": lat}, expert_rows
+        return {"latent": P()}
+    return dict.fromkeys(("k", "v"), P(None, None, None, "model"))
 
 
 def _paged_stack(comm, cfg, params, pool, page_table, x, positions,
                  page_size):
-    """Run the layer stack against paged KV pools.  One code path for
-    prefill (L = prompt bucket) and decode (L = 1): identical traced ops
-    per row is what makes the engine's batched-vs-alone decode tokens
-    bit-identical (DESIGN.md §15).
+    """Run the layer stack against the paged pool: one scan over each
+    param group the model has (`layers`; `pairs.local` + `pairs.global`,
+    two blocks a step; `dense_layers` then `layers`), carrying x and
+    taking each block's global layer index as scan input.  The whole pool
+    is a constant of the scans.  Prefill (L = prompt bucket, from
+    position 0) attends the prompt's own rows; decode (L = 1) reads the
+    pool in place by layer index.  Each layer's new rows leave the scans
+    as outputs and are written into the pool in one scatter per leaf
+    after them.  MoE layers compute their held expert share
+    (`L.moe_held`); in decode, slots at position 0 (the engine's empty
+    ones) route nowhere.  Returns (x, pool, expert rows (MoE layers,
+    experts_held), or None without MoE layers)."""
+    decode = x.shape[1] == 1
+    valid = positions > 0 if decode else None
 
-    Gather path: each layer's pool is a scan input and output, scattered
-    into and gathered from.  Kernel path (`L.paged_decode_kernel`): the
-    stacked pools stay whole as constants of the scan, each layer's
-    kernel reads its pages in place by layer index, and the layers' new
-    K/V rows leave the scan as outputs, written into the pools in one
-    scatter each after it."""
-    kernel = L.paged_decode_kernel(cfg, _tp(comm), x.shape[1])
-    if cfg.local_global_period:
-        names, local = ("pairs_local", "pairs_global"), (True, False)
-        bps = (params["pairs"]["local"], params["pairs"]["global"])
-    else:
-        names, local, bps = ("layers",), (False,), (params["layers"],)
-
-    def block(x, bp, kv, name, is_local):
+    def block(x, bp, layer, is_local):
         h = L.rms_norm(x, bp["ln1"])
-        if kernel:                              # kv: this layer's index
-            a, kv = L.attention_paged_decode(
-                comm, cfg, bp["attn"], h, pool[name], kv, page_table,
-                positions, page_size=page_size, is_local_layer=is_local)
-        else:                                   # kv: this layer's pool
-            a, kv = L.attention_paged(
-                comm, cfg, bp["attn"], h, kv, page_table, positions,
+        if cfg.attn == "mla" and decode:
+            a, rows = L.mla_paged_decode(comm, cfg, bp["attn"], h, pool,
+                                         layer, page_table, positions,
+                                         page_size=page_size)
+        elif cfg.attn == "mla":
+            a, rows = L.mla_paged_prefill(comm, cfg, bp["attn"], h,
+                                          positions)
+        elif decode:
+            a, rows = L.attention_paged_decode(
+                comm, cfg, bp["attn"], h, pool, layer, page_table, positions,
                 page_size=page_size, is_local_layer=is_local)
+        else:
+            a, rows = L.attention_paged_prefill(
+                comm, cfg, bp["attn"], h, positions,
+                keys=page_table.shape[1] * page_size, is_local_layer=is_local)
         x = x + a
         h = L.rms_norm(x, bp["ln2"])
-        return x + L.mlp(comm, cfg, bp["mlp"], h), kv
+        if "moe" in bp:
+            m, experts = L.moe_held(comm, cfg, bp["moe"], h, valid)
+            return x + m, rows, experts
+        return x + L.mlp(comm, cfg, bp["mlp"], h), rows, None
 
     def step(x, xs):
-        outs = []
-        for bp, kv, name, is_local in zip(*xs, names, local):
-            x, kv = block(x, bp, kv, name, is_local)
-            outs.append(kv)
-        return x, outs
+        bps, layers = xs
+        rows = []
+        for i, bp in enumerate(bps):            # a pair's first is local
+            x, r, experts = block(x, bp, layers[i], len(bps) == 2 and i == 0)
+            rows.append(r)
+        return x, (jax.tree.map(lambda *r: jnp.stack(r), *rows), experts)
 
-    n = jax.tree.leaves(bps[0])[0].shape[0]
-    kvs = [jnp.arange(n, dtype=jnp.int32) if kernel else pool[name]
-           for name in names]
-    x, outs = _scan(cfg, step, x, (bps, kvs))
-    if kernel:
-        outs = [{c: L.paged_kv_write_rows(pool[name][c], page_table,
-                                          rows[c], positions[:, 0],
-                                          page_size)
-                 for c in ("k", "v")} for name, rows in zip(names, outs)]
-    return x, dict(zip(names, outs))
+    if cfg.local_global_period:
+        groups = [(params["pairs"]["local"], params["pairs"]["global"])]
+    else:
+        groups = [(params[k],) for k in ("dense_layers", "layers")
+                  if k in params]
+    rows, expert_rows, first = [], None, 0
+    for bps in groups:
+        n = jax.tree.leaves(bps)[0].shape[0] * len(bps)
+        layers = jnp.arange(first, first + n, dtype=jnp.int32)
+        x, (r, experts) = _scan(cfg, step, x,
+                                (bps, layers.reshape(-1, len(bps))))
+        rows.append(jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), r))
+        expert_rows = experts if experts is not None else expert_rows
+        first += n
+    rows = jax.tree.map(lambda *r: jnp.concatenate(r), *rows)
+    pool = {c: L.paged_kv_write_block(pool[c], page_table, rows[c],
+                                      positions, page_size) for c in pool}
+    return x, pool, expert_rows
 
 
 def prefill_paged(comm: Comm, cfg: ModelConfig, params: Params, pool: Params,
@@ -633,17 +593,13 @@ def prefill_paged(comm: Comm, cfg: ModelConfig, params: Params, pool: Params,
     """Paged prefill fast-path: ONE forward pass over the whole prompt
     bucket that also fills the sequence's KV pages (vs the seed launcher's
     per-token teacher forcing).  tokens: (B, L_bucket); positions: (B,
-    L_bucket).  Returns (full-bucket logits (B, L, vocab_local), pool).
-    Rows past the true prompt length write garbage K/V into the row's own
-    reserved (or null) pages; decode overwrites each position before the
-    causal mask can ever expose it."""
+    L_bucket), from 0.  Returns (full-bucket logits (B, L, vocab_local),
+    pool).  Rows past the true prompt length write garbage K/V into the
+    row's own reserved (or null) pages; decode overwrites each position
+    before the causal mask can ever expose it."""
     x = _embed_scaled(comm, cfg, params, tokens)
-    if cfg.attn == "mla":
-        x, pool, _ = _paged_stack_latent(comm, cfg, params, pool, page_table,
-                                         x, positions, page_size)
-    else:
-        x, pool = _paged_stack(comm, cfg, params, pool, page_table, x,
-                               positions, page_size)
+    x, pool, _ = _paged_stack(comm, cfg, params, pool, page_table, x,
+                              positions, page_size)
     x = L.rms_norm(x, params["final_norm"])
     return L.lm_logits(comm, cfg, params["embed"], x), pool
 
@@ -652,19 +608,13 @@ def decode_step_paged(comm: Comm, cfg: ModelConfig, params: Params,
                       pool: Params, page_table, tokens, positions, *,
                       page_size: int):
     """One paged decode step: tokens (B,1), positions (B,) -> (logits
-    (B,1,vocab_local), pool), and for the MoE+MLA family a third output,
-    the token rows each held expert of each MoE layer computed (MoE
-    layers, experts_held).  Identical to `decode_step` numerics on a
+    (B,1,vocab_local), pool), and for a model with MoE layers a third
+    output, the token rows each held expert of each MoE layer computed
+    (MoE layers, experts_held).  Identical to `decode_step` numerics on a
     full-length cache; reads are page-table indexed."""
     x = _embed_scaled(comm, cfg, params, tokens)
-    extra = ()
-    if cfg.attn == "mla":
-        x, pool, expert_rows = _paged_stack_latent(
-            comm, cfg, params, pool, page_table, x, positions[:, None],
-            page_size)
-        extra = (expert_rows,)
-    else:
-        x, pool = _paged_stack(comm, cfg, params, pool, page_table, x,
-                               positions[:, None], page_size)
+    x, pool, expert_rows = _paged_stack(comm, cfg, params, pool, page_table,
+                                        x, positions[:, None], page_size)
     x = L.rms_norm(x, params["final_norm"])
-    return (L.lm_logits(comm, cfg, params["embed"], x), pool) + extra
+    logits = L.lm_logits(comm, cfg, params["embed"], x)
+    return (logits, pool) + (() if expert_rows is None else (expert_rows,))

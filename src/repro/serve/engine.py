@@ -177,13 +177,10 @@ def serve_programs(cfg, mesh, *, page_size: int, backend: str = "shmem",
     prefill(params, pool, table (1, max_pages), tokens (1, Lb), positions
     (1, Lb), last_idx (1,)) and decode(params, pool, table (B, max_pages),
     tokens (B, 1), positions (B,)) each return (greedy tokens, f32
-    logits, pool); for the MoE+MLA family decode also returns the token
-    rows each held expert of each MoE layer computed.  Decode donates the
-    pool: the kernel path writes the step's new rows into it in place;
-    so does the MoE+MLA prefill, whose latent rows are written after its
-    layers in one scatter.  An MLA latent pool is replicated over the
-    model axis (every head reads the one latent row); K/V pools are split
-    over it by head."""
+    logits, pool); decode also returns a tuple of what the stack returns
+    beside the pool (the held experts' token rows of a model with MoE
+    layers, else nothing).  Both donate the pool: every layer's new
+    rows are written into it in place, in one scatter after the layers."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -194,12 +191,8 @@ def serve_programs(cfg, mesh, *, page_size: int, backend: str = "shmem",
     from . import step as sstep
 
     axes = build.axis_spec(mesh)
-    _, tp, _ = build.mesh_dims(mesh)
     _, pspecs = build.abstract_params(cfg, mesh)
-    poolspecs = jax.tree.map(
-        lambda _: P(None, None, None, None if cfg.attn == "mla" else "model"),
-        jax.eval_shape(lambda: transformer.init_kv_pool(cfg, tp, 1,
-                                                        page_size)))
+    poolspecs = transformer.kv_pool_specs(cfg)
 
     def prefill_fn(params, pool, table, tokens, positions, last_idx):
         comm = Comm(axes, backend, **comm_kw)
@@ -208,8 +201,7 @@ def serve_programs(cfg, mesh, *, page_size: int, backend: str = "shmem",
             page_size=page_size)
         lg = jnp.take_along_axis(
             logits, last_idx[:, None, None], axis=1)[:, 0]
-        tok = sstep.sample_greedy(comm, lg)
-        return tok, lg, pool
+        return sstep.sample_greedy(comm, lg), lg, pool
 
     def decode_fn(params, pool, table, tokens, positions):
         comm = Comm(axes, backend, **comm_kw)
@@ -217,18 +209,15 @@ def serve_programs(cfg, mesh, *, page_size: int, backend: str = "shmem",
             comm, cfg, params, pool, table, tokens, positions,
             page_size=page_size)
         lg = logits[:, 0]
-        tok = sstep.sample_greedy(comm, lg)
-        return (tok, lg, pool, *extra)
+        return sstep.sample_greedy(comm, lg), lg, pool, tuple(extra)
 
-    lg_spec = P(None, "model")
-    latent = cfg.attn == "mla"
-    extra_specs = (P(),) if latent else ()
+    outs = (P(), P(None, "model"), poolspecs)
     pjit = jax.jit(build.shard_mapped(
-        prefill_fn, mesh, (pspecs, poolspecs, P(), P(), P(), P()),
-        (P(), lg_spec, poolspecs)), donate_argnums=(1,) if latent else ())
+        prefill_fn, mesh, (pspecs, poolspecs, P(), P(), P(), P()), outs),
+        donate_argnums=1)
     djit = jax.jit(build.shard_mapped(
-        decode_fn, mesh, (pspecs, poolspecs, P(), P(), P()),
-        (P(), lg_spec, poolspecs) + extra_specs), donate_argnums=1)
+        decode_fn, mesh, (pspecs, poolspecs, P(), P(), P()), outs + (P(),)),
+        donate_argnums=1)
     return pjit, djit, poolspecs
 
 
@@ -251,9 +240,8 @@ class ServeEngine:
     def __init__(self, cfg, mesh, *, params=None, max_slots: int = 4,
                  page_size: int = 8, max_seq: int = 64,
                  prompt_bucket: int = 32, kv_heap_bytes: int | None = None,
-                 backend: str = "shmem", allreduce_algo: str = "paper",
-                 topo=None, link=None, embedding=None, tuner=None,
-                 profile=None, metrics=None, eos_id: int | None = None,
+                 backend: str = "shmem", tuner=None, profile=None,
+                 metrics=None, eos_id: int | None = None,
                  init_key: int = 0, capture_logits: bool = False):
         import dataclasses as dc
 
@@ -314,8 +302,6 @@ class ServeEngine:
         from ..core.trace import Tracer
         self._trace = profile if isinstance(profile, Tracer) else None
 
-        comm_kw = dict(allreduce_algo=allreduce_algo, topo=topo, link=link,
-                       embedding=embedding, tuner=tuner, profile=profile)
         n_dev_pages = pool.num_pages
 
         with jax.set_mesh(mesh):
@@ -324,7 +310,8 @@ class ServeEngine:
                 params = jax.jit(init_fn)(jax.random.key(init_key))
             self.params = params
             self._pjit, self._djit, poolspecs = serve_programs(
-                cfg, mesh, page_size=page_size, backend=backend, **comm_kw)
+                cfg, mesh, page_size=page_size, backend=backend,
+                tuner=tuner, profile=profile)
             self.pool = jax.jit(build.shard_mapped(
                 lambda: transformer.init_kv_pool(cfg, tp, n_dev_pages,
                                                  page_size),
@@ -335,8 +322,8 @@ class ServeEngine:
             for Lb in self.prefill_buckets:
                 self._prefill(null_row, np.zeros(1, np.int32), Lb)
         jax.block_until_ready(self.pool)
-        self.decode_path = ("kernel" if layers.paged_decode_kernel(
-            cfg, tp, 1) else "gather")
+        self.decode_path = ("kernel" if layers.paged_decode_kernel(cfg, tp)
+                            else "gather")
         self.kv_kind = "latent" if cfg.attn == "mla" else "gqa"
         # (MoE layers, held experts) token rows of the last decode step
         self.last_expert_rows = None
@@ -543,11 +530,11 @@ class ServeEngine:
                                 path=self.decode_path,
                                 kv_pages=self._kv_pages(poss),
                                 kv_kind=self.kv_kind):
-                    tok, lg, self.pool, *extra = self._djit(
+                    tok, lg, self.pool, extra = self._djit(
                         self.params, self.pool, *args)
                     # force sync: step complete (the expert rows, when
                     # there are any, come back in the same transfer)
-                    tok, *extra = self._jax.device_get((tok, *extra))
+                    tok, extra = self._jax.device_get((tok, extra))
                     if extra:
                         self.last_expert_rows = extra[0]
                 if metrics is not None:

@@ -1,6 +1,6 @@
-"""The paged-decode Pallas kernel (interpret mode) against the serving
-engine's XLA path: scatter the new row into the pool, gather every page
-of the slot, attend with `_attend_mq` under the causal(+window) mask."""
+"""The paged-decode Pallas kernel (interpret mode) against its gather
+reference, `layers.kv_attend_gather`: gather every page of the slot, add
+the new row, attend with `_attend_mq` under the causal(+window) mask."""
 import dataclasses
 import math
 
@@ -51,16 +51,9 @@ def _kernel(q, new, pool, table, pos, page_size, window, softcap,
 def _gather_path(q, new, pool, table, pos, page_size, window, softcap):
     cfg = dataclasses.replace(smoke_config("qwen2-0.5b"), head_dim=HD,
                               softcap=softcap)
-    B = q.shape[0]
-    positions = pos[:, None]
-    ck, cv = (L.paged_kv_gather(L.paged_kv_update(
-        pool[c][LAYER], table, new[c][:, None], positions, page_size),
-        table).reshape(B, -1, K, HD) for c in "kv")
-    kv_pos = jnp.arange(ck.shape[1])[None, None, :]
-    valid = kv_pos <= positions[:, :, None]
-    if window is not None:
-        valid &= kv_pos > positions[:, :, None] - window
-    return L._attend_mq(cfg, q[:, None], ck, cv, valid)[:, 0]
+    qf = q.astype(jnp.float32) / math.sqrt(HD)
+    return L.kv_attend_gather(cfg, qf, new["k"], new["v"], pool,
+                              jnp.int32(LAYER), table, pos, window=window)
 
 
 @pytest.mark.parametrize("window,softcap", [(None, None), (20, 5.0)],

@@ -297,6 +297,50 @@ def test_engine_eos_stops_early(prompts):
     assert np.array_equal(eng2.results[r2], toks[:stop])
 
 
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "gemma2-9b"])
+def test_engine_logits_match_teacher_forced_forward(arch):
+    """Every step's logits, prefill and decode, against
+    `transformer.forward` over the prompt and the tokens served before
+    it.  gemma2's local/global pairs (window 16, softcap) read pages past
+    the window, so its local layers mask earlier positions."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro.configs import smoke_config
+    from repro.launch import build
+    from repro.launch.mesh import make_mesh
+    from repro.models import layers as L
+    from repro.models import transformer
+    from repro.parallel.comm import Comm
+    from repro.serve.engine import ServeEngine
+
+    cfg, mesh = smoke_config(arch), make_mesh(1, 1)
+    eng = ServeEngine(cfg, mesh, max_slots=3, page_size=8, max_seq=48,
+                      prompt_bucket=32, capture_logits=True)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, cfg.vocab, size=n).astype(np.int32)
+               for n in (5, 21, 30)]
+    rids = [eng.submit(p, 12) for p in prompts]
+    eng.run()
+
+    def fwd(params, tokens):
+        comm = Comm(build.axis_spec(mesh), "shmem")
+        h, _ = transformer.forward(comm, eng.cfg, params, tokens)
+        return L.lm_logits(comm, eng.cfg, params["embed"], h)
+    _, pspecs = build.abstract_params(eng.cfg, mesh)
+    with jax.set_mesh(mesh):
+        forward = jax.jit(build.shard_mapped(fwd, mesh, (pspecs, P()), P()))
+        for rid, p in zip(rids, prompts):
+            toks = eng.results[rid]
+            seq = np.concatenate([p, toks[:-1]])
+            want = np.asarray(forward(eng.params, jnp.asarray(seq)[None])[0],
+                              np.float32)[len(p) - 1:]
+            got = np.stack(eng.logits_trace[rid])
+            assert got.shape == want.shape
+            err = np.abs(got - want).max()
+            assert err < 5e-2, (arch, rid, err)   # bf16 compute
+
+
 def test_engine_phases_on_profiler_clock(prompts, tmp_path):
     """The engine's phases are host spans of the profiler's own trace,
     nested under serve.step: one serve.prefill per admission, one
@@ -335,14 +379,16 @@ def test_engine_phases_on_profiler_clock(prompts, tmp_path):
 
 def test_program_texts_hold_every_scope():
     """The compiled prefill and decode programs carry the model's named
-    scopes in their instructions' metadata."""
+    scopes in their instructions' metadata.  Only decode gathers pages:
+    prefill attends the prompt's own rows."""
     import re
 
     texts = _make_engine().program_texts()
     assert sorted(texts) == ["decode_fn", "prefill_fn"]
-    for text in texts.values():
-        for scope in ("kv_update", "kv_gather", "attend", "attn_proj",
-                      "mlp", "lm_head", "sample"):
+    for name, text in texts.items():
+        for scope in ("kv_update", "attend", "attn_proj", "mlp", "lm_head",
+                      "sample") + (("kv_gather",) if name == "decode_fn"
+                                   else ()):
             assert re.search(rf'op_name="[^"]*/{scope}/', text), scope
 
 

@@ -138,10 +138,13 @@ def test_paged_decode_latent_kernel_cell_widths(spec):
              spec((slots,), jnp.int32))
 
 
-def test_paged_decode_program_updates_pool_in_place(topo, monkeypatch):
-    """The serving engine's decode program at the cell's size, on the
-    kernel path: the pool is donated and aliased to the output, and no
-    instruction makes a copy of it or a layer-sized slice of it."""
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_decode_program_updates_pool_in_place(topo, monkeypatch,
+                                                    program):
+    """The serving engine's programs at the cell's size, decode on the
+    kernel path and prefill at the 1024 bucket: the pool is donated and
+    aliased to the output, and no instruction makes a copy of it or a
+    layer-sized slice of it."""
     from repro.configs import get_config
     from repro.kernels import ops as kops
     from repro.launch import build
@@ -152,7 +155,7 @@ def test_paged_decode_program_updates_pool_in_place(topo, monkeypatch):
     monkeypatch.setattr(kops, "_default_interpret", lambda: False)
     cfg = dataclasses.replace(get_config("qwen2-0.5b"), fsdp=False)
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
-    _, decode, poolspecs = serve_programs(cfg, mesh, page_size=PAGE)
+    prefill, decode, poolspecs = serve_programs(cfg, mesh, page_size=PAGE)
     shapes, pspecs = build.abstract_params(cfg, mesh)
 
     def on_mesh(s, sp):
@@ -168,10 +171,15 @@ def test_paged_decode_program_updates_pool_in_place(topo, monkeypatch):
         return jax.ShapeDtypeStruct(shape, jnp.int32,
                                     sharding=NamedSharding(mesh, P()))
     with jax.set_mesh(mesh):
-        compiled = decode.lower(params, pool, i32(SLOTS, PAGES),
-                                i32(SLOTS, 1), i32(SLOTS)).compile()
+        if program == "decode":
+            compiled = decode.lower(params, pool, i32(SLOTS, PAGES),
+                                    i32(SLOTS, 1), i32(SLOTS)).compile()
+        else:
+            compiled = prefill.lower(params, pool, i32(1, PAGES),
+                                     i32(1, 1024), i32(1, 1024),
+                                     i32(1)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    assert ("tpu_custom_call" in text) == (program == "decode")
     pool_bytes = 2 * LAYERS * POOL_PAGES * PAGE * 128 * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
     layer_elems = POOL_PAGES * PAGE * 128
